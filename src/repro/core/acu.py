@@ -226,10 +226,35 @@ class MatmulPlan:
     def __call__(self, *args) -> Array:
         return self.fn(*args)
 
+    @property
+    def route(self) -> str:
+        if self.fused:
+            return "fused_lut_dense"
+        if self.mode in (AcuMode.EXACT, AcuMode.FACTORED):
+            return f"{self.mode.value}_int_matmul"
+        if self.use_pallas and self.mode in (AcuMode.LUT, AcuMode.LOWRANK):
+            return "lut_matmul" if self.mode == AcuMode.LUT else "err_matmul"
+        return f"{self.mode.value}_jnp_oracle"
+
+    def describe(self) -> dict:
+        part = self.partition
+        return {"route": self.route, "mode": self.mode.value,
+                "bits": self.bits,
+                "partition": None if part is None else
+                    f"rows{part.rows}x cols{part.cols}x k{part.k}"}
+
+
+def _no_oracle_on_tpu(what: str, report) -> None:
+    """The jnp oracles and exact fallbacks are CPU references: on the TPU a
+    plan that would resolve to one raises instead of running it."""
+    from repro.kernels.runtime import on_tpu
+    if on_tpu():
+        raise RuntimeError(f"{what} has no kernel route on the TPU: {report}")
+
 
 def _resolve_unfused(acu: Acu) -> Callable[[Array, Array], Array]:
-    """The unfused integer-operand GEMM for ``acu`` (pure-jnp oracles or the
-    per-mode Pallas kernels)."""
+    """The unfused integer-operand GEMM for ``acu`` (native int matmuls, the
+    per-mode Pallas kernels, or — off the TPU only — the jnp oracles)."""
     if acu.mode == AcuMode.EXACT:
         def fn(a, w):
             if acu.bits <= 8:
@@ -246,7 +271,8 @@ def _resolve_unfused(acu: Acu) -> Callable[[Array, Array], Array]:
         if acu.use_pallas:
             from repro.kernels.lut_matmul import ops as lops
             return lambda a, w: lops.lut_matmul(
-                a, w, jnp.asarray(acu.lut), acu.offset, interpret=acu.interpret)
+                a, w, acu.lut, acu.offset, interpret=acu.interpret)
+        _no_oracle_on_tpu("LUT GEMM", "use_pallas=False")
         if acu.lut_chunk == 0:
             # paper's "baseline approximate": LUTs without the
             # vectorization/chunking optimizations — one (M, K, N) gather
@@ -261,8 +287,10 @@ def _resolve_unfused(acu: Acu) -> Callable[[Array, Array], Array]:
             return lambda a, w: eops.err_matmul(
                 a, w, jnp.asarray(acu.lowrank.f), jnp.asarray(acu.lowrank.g),
                 acu.offset, interpret=acu.interpret)
+        _no_oracle_on_tpu("LOWRANK GEMM", "use_pallas=False")
         return acu._lowrank_matmul_jnp
     # FUNCTIONAL: stream over K chunks to bound the (M, Kc, N) intermediate
+    _no_oracle_on_tpu("FUNCTIONAL GEMM", "jnp only")
     return acu._functional_matmul_jnp
 
 
@@ -287,7 +315,8 @@ def matmul_plan(acu: Acu, *, a_bits: Optional[int] = None,
     (defaults to the ACU operand width). A fused request that cannot be
     served — non-LUT mode, no Pallas routing, or no table — silently falls
     back to the unfused plan, so callers can request fusion unconditionally
-    and keep the pure-jnp implementations as bit-exact oracles.
+    and keep the pure-jnp implementations as bit-exact oracles. On the TPU
+    an unfused plan that would be a jnp oracle raises instead.
 
     ``mesh``: ``None`` auto-detects the active
     :class:`~repro.parallel.sharding.MeshContext` (plans resolved under
@@ -312,10 +341,11 @@ def matmul_plan(acu: Acu, *, a_bits: Optional[int] = None,
         from repro.kernels.fused_lut_dense import ops as fops
 
         def fused_call(x, wq, x_scale, x_zp, w_scale, *, emit_acc=False):
-            # jnp.asarray stays inside fn: plans are cached across jit traces
-            # and a device constant created during one trace must not leak
-            # into another
-            return fops.fused_lut_dense(x, wq, jnp.asarray(acu.lut),
+            # the host table goes in as is: plans are cached across jit
+            # traces (a device constant made in one must not leak into
+            # another), and the kernel wrapper reads its digit-plane count
+            # from the concrete values
+            return fops.fused_lut_dense(x, wq, acu.lut,
                                         acu.offset, x_scale, x_zp, w_scale,
                                         bits=a_bits, interpret=acu.interpret,
                                         emit_acc=emit_acc)
@@ -376,8 +406,8 @@ def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
         from repro.kernels.fused_lut_dense import ops as fops
 
         def bwd_call(a, b, sa, sb, *, emit_acc=False):
-            # jnp.asarray stays inside fn: see fused_call in matmul_plan
-            return fops.fused_lut_bwd(a, b, jnp.asarray(acu.lut), acu.offset,
+            # host table as is: see fused_call in matmul_plan
+            return fops.fused_lut_bwd(a, b, acu.lut, acu.offset,
                                       sa, sb, bits=a_bits,
                                       interpret=acu.interpret,
                                       emit_acc=emit_acc)
@@ -881,7 +911,7 @@ def attn_plan(acu: Acu, spec: AttnSpec, *, a_bits: Optional[int] = None,
     (non-LUT mode, no Pallas routing, no table) resolves to ``"dense"`` —
     the caller keeps its exact float attention. ``route`` pins one
     explicitly (``"fused_attn"`` raises if unavailable; ``"dense"`` forces
-    the exact path).
+    the exact path). On the TPU the unpinned fallback raises instead.
 
     There is no unfused approximate attention route on purpose: the unfused
     composition (``approx_attention_ref``) exists as the bit-exactness
@@ -915,6 +945,8 @@ def attn_plan(acu: Acu, spec: AttnSpec, *, a_bits: Optional[int] = None,
                           "layout-independent)")
     if route == fused_route and not can_fuse:
         raise ValueError(f"{fused_route} route unavailable: {report}")
+    if not can_fuse and route != "dense":
+        _no_oracle_on_tpu("attention", report)
     if route == "dense" or not can_fuse:
         if route == "dense":
             report.append("route pinned to exact dense attention by caller")
@@ -928,18 +960,17 @@ def attn_plan(acu: Acu, spec: AttnSpec, *, a_bits: Optional[int] = None,
     rep = spec.hq // spec.hkv
 
     def attn_call(qf, kf, vf, qs, ks, vs, rowinfo):
-        # folded (B*H, S, D) operands; jnp.asarray stays inside fn: plans
-        # are cached across jit traces and a device constant created during
-        # one trace must not leak into another
+        # folded (B*H, S, D) operands; the host table goes in as is (see
+        # fused_call in matmul_plan)
         return approx_flash_attention(
-            qf, kf, vf, jnp.asarray(acu.lut), acu.offset, qs, ks, vs,
+            qf, kf, vf, acu.lut, acu.offset, qs, ks, vs,
             bits=a_bits, causal=spec.causal, window=spec.window,
             softcap=spec.softcap, rowinfo=rowinfo, bq=spec.bq, bk=spec.bk,
             interpret=acu.interpret)
 
     def attn_call_paged(qf, k_pool, v_pool, qs, ks, vs, rowinfo, pt):
         return approx_flash_attention_paged(
-            qf, k_pool, v_pool, jnp.asarray(acu.lut), acu.offset, qs, ks, vs,
+            qf, k_pool, v_pool, acu.lut, acu.offset, qs, ks, vs,
             bits=a_bits, causal=spec.causal, window=spec.window,
             softcap=spec.softcap, rowinfo=rowinfo, page_table=pt, rep=rep,
             bq=spec.bq, interpret=acu.interpret)
@@ -1123,11 +1154,9 @@ def grouped_plan(acu: Acu, spec: GroupedSpec, *, a_bits: Optional[int] = None,
     from repro.kernels.fused_lut_grouped import ops as gops
 
     def grouped_call(xe, wq, xs, xz, ws, counts, *, emit_acc=False):
-        # jnp.asarray stays inside fn: plans are cached across jit traces
-        # and a device constant created during one trace must not leak
-        # into another
-        return gops.fused_lut_grouped(xe, wq, jnp.asarray(acu.lut),
-                                      acu.offset, xs, xz, ws, counts,
+        # host table as is: see fused_call in matmul_plan
+        return gops.fused_lut_grouped(xe, wq, acu.lut, acu.offset, xs, xz,
+                                      ws, counts,
                                       bits=a_bits, interpret=acu.interpret,
                                       emit_acc=emit_acc)
 

@@ -79,6 +79,13 @@ def _affine_matmul_dequant(acc: Array, xqp: QParams, wqp: QParams) -> Array:
 
 
 _STE_CACHE: dict = {}
+_RESOLVED: dict = {}    # cache key -> describe() of the plan it resolved
+
+
+def resolved_plans() -> list[dict]:
+    """``describe()`` of every GEMM and attention plan this process has
+    resolved so far (GEMM entries add the STE backward's ``bwd_route``)."""
+    return list(_RESOLVED.values())
 
 
 def _mesh_cache_key(ctx):
@@ -114,6 +121,11 @@ def _get_ste_fn(acu: Acu, a_bits: int, w_bits: int, fused: bool = False,
         return _STE_CACHE[key]
 
     plan = matmul_plan(acu, a_bits=a_bits, fused=fused, mesh=ctx or False)
+    # matmul_bwd_plan serves the fused backward kernel exactly when the
+    # forward plan is fused
+    bwd_route = ("exact_f32" if not approx_bwd else
+                 "fused_lut_bwd" if plan.fused else "quantized_unfused")
+    _RESOLVED[key] = {"op": "gemm", **plan.describe(), "bwd_route": bwd_route}
     if approx_bwd:
         from .acu import matmul_bwd_plan
         gx_bwd, gw_bwd = matmul_bwd_plan(acu, a_bits=a_bits, fused=fused,
@@ -389,6 +401,7 @@ def _get_attn_plan(acu: Acu, a_bits: int, spec, ctx):
         return _STE_CACHE[key]
     plan = attn_plan(acu, spec, a_bits=a_bits, mesh=ctx or False)
     _STE_CACHE[key] = plan
+    _RESOLVED[key] = {"op": "attention", **plan.describe()}
     return plan
 
 
@@ -431,11 +444,12 @@ def approx_attention_paged(q: Array, k_pool: Array, v_pool: Array,
 
     ``q``: (B, Hq, Sq, D); ``k_pool``/``v_pool``: (Hkv, P, bk, D) physical
     block pools; ``page_table``: (B, n_logical) int32; ``rowinfo``: (B, 3)
-    int32 — both REQUIRED. The K/V calibration amaxes run over the blocks
-    the page tables actually reference (``pool[:, page_table]``), NOT the
-    whole pool: a prefix-cache hit must see exactly the scales a cold run
-    of the same request would compute, and the pool's unrelated residents
-    (other requests, stale freed blocks) must never perturb them.
+    int32 — both REQUIRED. The K/V calibration amaxes run over each row's
+    written extent ``[kv_start, kv_len)`` of the blocks its page table
+    references, NOT the whole pool nor whole blocks: a prefix-cache hit
+    must see exactly the scales a cold run of the same request would
+    compute, and the pool's unrelated residents (other requests, stale
+    contents of reused blocks) must never perturb them.
     """
     from .acu import AttnSpec
     from .quantization import inline_symmetric_scale
@@ -448,8 +462,15 @@ def approx_attention_paged(q: Array, k_pool: Array, v_pool: Array,
     if plan.route != "fused_attn_paged":
         return None
     pt = jnp.asarray(page_table, jnp.int32)
+    info = jnp.asarray(rowinfo, jnp.int32)
+    # only each row's written extent [kv_start, kv_len) counts: a freshly
+    # allocated block still holds whatever its previous owner wrote
+    t = jnp.arange(pt.shape[1] * k_pool.shape[2]).reshape(1, *pt.shape[1:],
+                                                          -1)
+    valid = (t >= info[:, 1, None, None]) & (t < info[:, 2, None, None])
     amaxes = (jnp.maximum(jnp.max(jnp.abs(q)), 1e-6),) + tuple(
-        jnp.maximum(jnp.max(jnp.abs(pool[:, pt])), 1e-6)
+        jnp.maximum(jnp.max(jnp.where(valid[None, ..., None],
+                                      jnp.abs(pool[:, pt]), 0.0)), 1e-6)
         for pool in (k_pool, v_pool))
     scales = [inline_symmetric_scale(a, cfg.a_bits) for a in amaxes]
     return plan(q, k_pool, v_pool, *scales, rowinfo, pt)
@@ -582,7 +603,7 @@ def _conv_bwd_fns(acu: Acu, plan, a_bits: int, ctx):
         return gw.transpose(2, 1, 0).reshape(cout, cin, kh, kw)
 
     def gx_acc(a, b, sa, sb):
-        return fops.fused_lut_bwd(a, b, jnp.asarray(acu.lut), acu.offset,
+        return fops.fused_lut_bwd(a, b, acu.lut, acu.offset,
                                   sa, sb, bits=a_bits,
                                   interpret=acu.interpret, emit_acc=True)
 

@@ -47,27 +47,6 @@ class QParams:
         return jnp.reshape(v, shape)
 
 
-def _register_barrier_batcher() -> None:
-    """``optimization_barrier`` has no vmap rule in this jax version; it is
-    an elementwise identity, so the batched rule is the barrier itself with
-    unchanged batch dims (needed for the vmapped grouped-conv GEMM)."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-        if optimization_barrier_p not in batching.primitive_batchers:
-            def _batcher(args, dims, **params):
-                return optimization_barrier_p.bind(*args, **params), dims
-            batching.primitive_batchers[optimization_barrier_p] = _batcher
-    except (ImportError, AttributeError):
-        # newer jax: the rule exists or the internals moved/were pruned —
-        # degrade to the one feature needing it (vmapped grouped conv)
-        # rather than failing the whole package at import time
-        pass
-
-
-_register_barrier_batcher()
-
-
 _PIN_INT = {2: jnp.int16, 4: jnp.int32, 8: jnp.int64}
 
 

@@ -17,8 +17,8 @@ into the streaming-softmax loop. Per (batch*head, q_block) grid step:
 
 Emulation semantics (what "approximate attention on the ACU" means here):
 
-* *structural* padding this wrapper introduces (head-dim pad to the gather
-  chunk, Sk pad to the key-block multiple) is corrected in integer space, so
+* *structural* padding this wrapper introduces (head-dim pad, Sk pad to
+  the key-block multiple) is corrected in integer space, so
   the result is independent of the tile geometry — exactly like the dense
   and conv kernels;
 * *masked keys that exist in the input* (left-pad slots below ``kv_start``,
@@ -33,19 +33,20 @@ Emulation semantics (what "approximate attention on the ACU" means here):
   executed) is part of the defined semantics, and the oracle replicates it.
 
 The running max/exp/rescale stays in float32, and float32 online-softmax
-arithmetic is where the bitwise contract gets subtle: XLA's CPU backend
+arithmetic is where the bitwise contract gets subtle. On the CPU, XLA
 contracts ``a*b + c`` into an FMA under jit — straight through
-``optimization_barrier`` and even bitcast round-trips (the same contraction
-behind the documented 1-ulp partitioned bias-add caveat from the sharding
-work). No graph-level fence stops it, so instead of trying to pin each
-multiply we pin the *structure*: the entire per-KV-block update lives in
+``optimization_barrier`` — so instead of pinning each multiply the
+*structure* is pinned: the entire per-KV-block update lives in
 :func:`_online_block`, shared verbatim by the Pallas kernel and the jnp
-oracle (the PR-4 "shared tap-accumulate core" idiom). Both sides compile
-the identical ``fori_loop`` body — a loop body is its own XLA computation,
-so surrounding context cannot re-fuse it — and both public entry points run
-their math under jit, which is why they agree bit for bit. Scales are
-pinned with ``pin_rounding`` OUTSIDE the kernel and passed in as (1,)
-operands, so single-device and sharded runs also see identical bits.
+oracle, and a loop body is its own computation that surrounding context
+cannot re-fuse. On the TPU the kernel is compiled by Mosaic and the oracle
+by XLA; their elementwise float ops round identically, but a reduction's
+association order is each compiler's own, so the one float reduction (the
+softmax row sum) is an explicit pairwise tree of adds (:func:`_row_sum`).
+Both LUT GEMMs are exact integer arithmetic on the shared core
+(:mod:`repro.kernels.lut_gather`). Scales are pinned with ``pin_rounding``
+OUTSIDE the kernel and passed in as SMEM scalars, so single-device and
+sharded runs also see identical bits.
 
 GQA shares KV through the BlockSpec index map (``b // rep``) — repeated K/V
 never exist in HBM.
@@ -59,15 +60,13 @@ rewrite: the KV block size *is* the kernel's ``bk`` tile, the pool arrives
 as one ``(Hkv, P*bk, D)`` operand (each grid row selects its KV head via
 the same ``(b // rep)`` BlockSpec index map, now mod ``Hkv``), and the only
 change inside the loop is where logical block ``ki`` starts —
-``page_table[ki] * bk`` instead of ``ki * bk``. ``_online_block`` grows an
-optional ``kv_blocks`` operand for exactly that indirection; with
-``kv_blocks=None`` the body is byte-identical to the contiguous path, and
-the paged oracle (:func:`~.ref.approx_attention_paged_ref`) drives the same
-body with the same page table, so paged == contiguous == oracle bitwise
-whenever the gathered blocks hold the same values as the contiguous layout
-(masked keys keep the faithful ``LUT[0, ·]`` contribution either way —
-which is why pool blocks must be zeroed on allocation, not on free: a
-recycled block's stale codes would be observable under biased multipliers).
+``page_table[ki] * bk`` instead of ``ki * bk``, read by the ``load_kv``
+callback ``_online_block`` takes. The paged oracle
+(:func:`~.ref.approx_attention_paged_ref`) drives the same body with the
+same page table, so paged == contiguous == oracle bitwise whenever the
+gathered blocks hold the same values as the contiguous layout (masked keys
+keep the faithful ``LUT[0, ·]`` contribution either way, so under a biased
+multiplier a recycled block's stale codes past ``kv_len`` are observable).
 """
 from __future__ import annotations
 
@@ -79,25 +78,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quantization import pin_rounding
+from repro.kernels.lut_gather import (SMEM, lut_gemm, round_up,
+                                     table_operands)
 from repro.kernels.runtime import resolve_interpret
 
 from .kernel import NEG_INF
 
 
-def _mul_barrier(a, b):
-    """``a * b`` behind an optimization barrier.
-
-    NOT sufficient on its own — XLA CPU contracts through barriers (see
-    module docstring) — but it keeps the graphs conservative on backends
-    that do honor it. The real bitwise guarantee is the shared
-    ``_online_block`` body.
-    """
-    return jax.lax.optimization_barrier(a * b)
-
-
 def _quantize_sym(x, scale, lo, hi, offset):
     """Per-tensor symmetric quantize to shifted ACU codes (zero-point 0)."""
     return jnp.clip(jnp.round(x / scale), lo, hi).astype(jnp.int32) + offset
+
+
+def _row_sum(p):
+    """Sum over the last (power-of-two) axis as an explicit pairwise tree of
+    elementwise adds. A reduction's association order is the compiler's
+    choice — Mosaic and XLA:TPU sum lanes in different orders — while
+    elementwise adds round the same everywhere, so the kernel and its XLA
+    oracle agree bit for bit on the chip too."""
+    w = p.shape[-1]
+    assert w & (w - 1) == 0, f"row width {w} is not a power of two"
+    while w > 1:
+        w //= 2
+        p = p[:, :w] + p[:, w:2 * w]
+    return p[:, 0]
 
 
 def attn_scales(q_scale, k_scale, v_scale, d_real: int, hi: int):
@@ -113,31 +117,11 @@ def attn_scales(q_scale, k_scale, v_scale, d_real: int, hi: int):
     return score, pv
 
 
-def _lut_gemm(a_codes, b_codes, lut, inner: int, n_codes: int):
-    """``out[i, n] = sum_j LUT[a[i, j], b[j, n]]`` — int32, streamed in
-    ``inner``-wide contraction chunks so the gather working set stays
-    (m, inner, n)."""
-    m_dim, k_dim = a_codes.shape
-    n_dim = b_codes.shape[1]
-
-    def step(i, acc):
-        a_sl = jax.lax.dynamic_slice(a_codes, (0, i * inner), (m_dim, inner))
-        b_sl = jax.lax.dynamic_slice(b_codes, (i * inner, 0), (inner, n_dim))
-        idx = a_sl[:, :, None] * n_codes + b_sl[None, :, :]
-        prods = jnp.take(lut, idx.reshape(-1), unique_indices=False,
-                         indices_are_sorted=False).reshape(m_dim, inner, n_dim)
-        return acc + prods.sum(axis=1)
-
-    return jax.lax.fori_loop(0, k_dim // inner, step,
-                             jnp.zeros((m_dim, n_dim), jnp.int32))
-
-
-def _online_block(ki, carry, *, qq, q_pos, k_all, v_all, lut, m00, sks, svs,
+def _online_block(ki, carry, *, qq, q_pos, load_kv, lut, m00, sks, svs,
                   score_scale, pv_scale, kv_start, kv_len, bq: int, bk: int,
-                  seq_k_real: int, d_real: int, n_codes: int, offset: int,
+                  seq_k_real: int, d_real: int, n_planes: int, offset: int,
                   lo: int, hi: int, causal: bool, window: int | None,
-                  softcap: float | None, inner_d: int, inner_k: int,
-                  kv_blocks=None):
+                  softcap: float | None):
     """One KV block of the approximate online softmax — the shared core.
 
     Kernel and oracle both drive this exact function inside the same
@@ -145,31 +129,24 @@ def _online_block(ki, carry, *, qq, q_pos, k_all, v_all, lut, m00, sks, svs,
     computation, which is what makes the two bitwise-identical (module
     docstring: FMA contraction cannot be fenced op-by-op on XLA CPU).
 
-    ``kv_blocks``: optional (n_logical_blocks,) int32 page-table row mapping
-    logical KV block ``ki`` to its physical block in the pool ``k_all`` /
-    ``v_all`` are laid out as. ``None`` keeps the contiguous layout
-    (physical start = ``ki * bk``) with a body byte-identical to the
-    pre-paged kernel; masking, positions and pad corrections always speak
-    *logical* coordinates, so the two layouts agree bit for bit when the
-    gathered blocks hold the same values.
+    ``load_kv(ki)`` returns the float (bk, dp) K and V blocks of *logical*
+    block ``ki``: the kernel reads them from its VMEM refs (through the page
+    table for the paged layout), the oracle slices whole arrays. Masking,
+    positions and pad corrections always speak logical coordinates, so the
+    two layouts agree bit for bit when the gathered blocks hold the same
+    values. Both int32 GEMMs run on the shared LUT-GEMM core
+    (:func:`~repro.kernels.lut_gather.lut_gemm`) over the padded table
+    ``lut``.
     """
     m, l, acc = carry
-    dp = k_all.shape[-1]
-    if kv_blocks is None:
-        start = ki * bk
-    else:
-        start = jax.lax.dynamic_index_in_dim(
-            kv_blocks, ki, keepdims=False).astype(jnp.int32) * bk
-    kf = jax.lax.dynamic_slice(k_all, (start, 0), (bk, dp)
-                               ).astype(jnp.float32)
-    vf = jax.lax.dynamic_slice(v_all, (start, 0), (bk, dp)
-                               ).astype(jnp.float32)
+    kf, vf = load_kv(ki)
+    dp = kf.shape[-1]
     kq = _quantize_sym(kf, sks, lo, hi, offset)
     vq = _quantize_sym(vf, svs, lo, hi, offset)
 
-    s_int = _lut_gemm(qq, kq.T, lut, inner_d, n_codes)         # (bq, bk)
+    s_int = lut_gemm(qq, kq.T, lut, n_planes=n_planes)         # (bq, bk)
     s_int = s_int - (dp - d_real) * m00
-    s = _mul_barrier(s_int.astype(jnp.float32), score_scale)
+    s = s_int.astype(jnp.float32) * score_scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
     k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -184,12 +161,12 @@ def _online_block(ki, carry, *, qq, q_pos, k_all, v_all, lut, m00, sks, svs,
     alpha = jnp.exp(m - m_new)
     # the normalizer accumulates the FLOAT probabilities; only the PV
     # contraction runs on the ACU
-    l_new = _mul_barrier(alpha, l) + p.sum(axis=-1)
+    l_new = alpha * l + _row_sum(p)
     pq = jnp.clip(jnp.round(p * hi), 0, hi).astype(jnp.int32) + offset
-    pv_int = _lut_gemm(pq, vq, lut, inner_k, n_codes)          # (bq, dp)
+    pv_int = lut_gemm(pq, vq, lut, n_planes=n_planes)          # (bq, dp)
     pv_int = pv_int - jnp.clip((ki + 1) * bk - seq_k_real, 0, bk) * m00
-    pv = _mul_barrier(pv_int.astype(jnp.float32), pv_scale)
-    acc_new = _mul_barrier(acc, alpha[:, None]) + pv
+    pv = pv_int.astype(jnp.float32) * pv_scale
+    acc_new = acc * alpha[:, None] + pv
     return m_new, l_new, acc_new
 
 
@@ -202,40 +179,34 @@ def causal_block_bound(q_base, qi: int, bq: int, bk: int, n_kv: int):
     return jnp.minimum(n_kv, (q_base + (qi + 1) * bq - 1) // bk + 1)
 
 
-def _approx_kernel(q_ref, k_ref, v_ref, lut_ref, info_ref, sq_ref, sk_ref,
-                   sv_ref, ss_ref, pvs_ref, o_ref, *, bq: int, bk: int,
-                   seq_k: int, seq_k_real: int, d_real: int, n_codes: int,
-                   offset: int, lo: int, hi: int, causal: bool,
-                   window: int | None, softcap: float | None, inner_d: int,
-                   inner_k: int):
+def _attend(q_ref, lut_ref, info_ref, sq_ref, sk_ref, sv_ref, ss_ref,
+            pvs_ref, m00_ref, o_ref, load_kv, *, n_kv: int, bq: int, bk: int,
+            seq_k_real: int, d_real: int, n_planes: int, offset: int, lo: int,
+            hi: int, causal: bool, window: int | None,
+            softcap: float | None):
+    """The kernel body both layouts share: quantize the q tile, walk the
+    visible KV blocks through ``_online_block``, normalize, store."""
+    b = pl.program_id(0)
     qi = pl.program_id(1)
     dp = q_ref.shape[-1]
-    lut = lut_ref[...]
-    m00 = lut[offset * n_codes + offset]
-    info = info_ref[...]
-    q_base, kv_start, kv_len = info[0, 0], info[0, 1], info[0, 2]
+    q_base, kv_start, kv_len = info_ref[b, 0], info_ref[b, 1], info_ref[b, 2]
 
-    qf = q_ref[...][0].astype(jnp.float32)                     # (bq, dp)
+    qf = q_ref[0].astype(jnp.float32)                          # (bq, dp)
     qq = _quantize_sym(qf, sq_ref[0], lo, hi, offset)
     q_pos = (q_base + qi * bq
              + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
-
-    k_all = k_ref[...][0]                                      # (seq_k, dp)
-    v_all = v_ref[...][0]
-
-    n_kv = seq_k // bk
     if causal:
         n_kv_eff = causal_block_bound(q_base, qi, bq, bk, n_kv)
     else:
         n_kv_eff = n_kv
 
     body = functools.partial(
-        _online_block, qq=qq, q_pos=q_pos, k_all=k_all, v_all=v_all, lut=lut,
-        m00=m00, sks=sk_ref[0], svs=sv_ref[0], score_scale=ss_ref[0],
+        _online_block, qq=qq, q_pos=q_pos, load_kv=load_kv, lut=lut_ref,
+        m00=m00_ref[0], sks=sk_ref[0], svs=sv_ref[0], score_scale=ss_ref[0],
         pv_scale=pvs_ref[0], kv_start=kv_start, kv_len=kv_len, bq=bq, bk=bk,
-        seq_k_real=seq_k_real, d_real=d_real, n_codes=n_codes, offset=offset,
-        lo=lo, hi=hi, causal=causal, window=window, softcap=softcap,
-        inner_d=inner_d, inner_k=inner_k)
+        seq_k_real=seq_k_real, d_real=d_real, n_planes=n_planes,
+        offset=offset, lo=lo, hi=hi, causal=causal, window=window,
+        softcap=softcap)
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     acc0 = jnp.zeros((bq, dp), jnp.float32)
@@ -244,50 +215,53 @@ def _approx_kernel(q_ref, k_ref, v_ref, lut_ref, info_ref, sq_ref, sk_ref,
     o_ref[...] = out[None]
 
 
+def _approx_kernel(q_ref, k_ref, v_ref, lut_ref, info_ref, sq_ref, sk_ref,
+                   sv_ref, ss_ref, pvs_ref, m00_ref, o_ref, *, bk: int,
+                   seq_k: int, **statics):
+    def load_kv(ki):
+        start = pl.multiple_of(ki * bk, bk)
+        return (k_ref[0, pl.ds(start, bk), :].astype(jnp.float32),
+                v_ref[0, pl.ds(start, bk), :].astype(jnp.float32))
+
+    _attend(q_ref, lut_ref, info_ref, sq_ref, sk_ref, sv_ref, ss_ref, pvs_ref,
+            m00_ref, o_ref, load_kv, n_kv=seq_k // bk, bk=bk, **statics)
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "seq_k_real", "d_real", "n_codes", "offset", "lo", "hi", "causal",
-    "window", "softcap", "bq", "bk", "rep", "inner_d", "inner_k", "interpret"))
-def approx_flash_attention_kernel(q, k, v, lut_flat, rowinfo, sqs, sks, svs,
-                                  score_scale, pv_scale, *, seq_k_real: int,
-                                  d_real: int, n_codes: int, offset: int,
-                                  lo: int, hi: int, causal: bool,
+    "seq_k_real", "d_real", "n_planes", "offset", "lo", "hi", "causal",
+    "window", "softcap", "bq", "bk", "rep", "interpret"))
+def approx_flash_attention_kernel(q, k, v, lut, rowinfo, sqs, sks, svs,
+                                  score_scale, pv_scale, m00, *,
+                                  seq_k_real: int, d_real: int, n_planes: int,
+                                  offset: int, lo: int, hi: int, causal: bool,
                                   window: int | None, softcap: float | None,
-                                  bq: int, bk: int, rep: int, inner_d: int,
-                                  inner_k: int,
+                                  bq: int, bk: int, rep: int,
                                   interpret: bool | None = None):
     """Pre-padded entry: q (B*Hq, Sq_p, Dp) f32, k/v (B*Hkv, Sk_p, Dp),
-    ``rowinfo`` (B*Hq, 3) int32 rows ``[q_base, kv_start, kv_len]``, five
-    (1,)-shaped f32 scale operands. Returns (B*Hq, Sq_p, Dp) float32."""
+    ``lut`` the (R, L) padded table, ``rowinfo`` (B*Hq, 3) int32 rows
+    ``[q_base, kv_start, kv_len]``, five (1,)-shaped f32 scale operands and
+    the (1,) int32 ``M[0, 0]``. Returns (B*Hq, Sq_p, Dp) float32."""
     bh, sq_p, dp = q.shape
     bh_kv, sk_p, _ = k.shape
     assert bh == bh_kv * rep, (bh, bh_kv, rep)
     assert sq_p % bq == 0 and sk_p % bk == 0, (sq_p, sk_p, bq, bk)
-    assert dp % inner_d == 0 and bk % inner_k == 0, (dp, inner_d, bk, inner_k)
-    grid = (bh, sq_p // bq)
-    scale_spec = pl.BlockSpec((1,), lambda b, i: (0,))
     return pl.pallas_call(
         functools.partial(_approx_kernel, bq=bq, bk=bk, seq_k=sk_p,
                           seq_k_real=seq_k_real, d_real=d_real,
-                          n_codes=n_codes, offset=offset, lo=lo, hi=hi,
-                          causal=causal, window=window, softcap=softcap,
-                          inner_d=inner_d, inner_k=inner_k),
-        grid=grid,
+                          n_planes=n_planes, offset=offset, lo=lo, hi=hi,
+                          causal=causal, window=window, softcap=softcap),
+        grid=(bh, sq_p // bq),
         in_specs=[
             pl.BlockSpec((1, bq, dp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, sk_p, dp), lambda b, i: (b // rep, 0, 0)),
             pl.BlockSpec((1, sk_p, dp), lambda b, i: (b // rep, 0, 0)),
-            pl.BlockSpec((n_codes * n_codes,), lambda b, i: (0,)),
-            pl.BlockSpec((1, 3), lambda b, i: (b, 0)),
-            scale_spec, scale_spec, scale_spec, scale_spec, scale_spec,
+            pl.BlockSpec(lut.shape, lambda b, i: (0, 0)),
+            SMEM, SMEM, SMEM, SMEM, SMEM, SMEM, SMEM,
         ],
         out_specs=pl.BlockSpec((1, bq, dp), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, dp), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(q, k, v, lut_flat, rowinfo, sqs, sks, svs, score_scale, pv_scale)
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
+    )(q, k, v, lut, rowinfo, sqs, sks, svs, score_scale, pv_scale, m00)
 
 
 def prepare_approx_attention(q, k, v, lut, offset, q_scale, k_scale, v_scale,
@@ -299,8 +273,7 @@ def prepare_approx_attention(q, k, v, lut, offset, q_scale, k_scale, v_scale,
     Returns ``(operands, statics)``: operands is the tuple the kernel takes
     positionally; statics is a dict of the static keyword arguments.
     """
-    n_codes = int(round(lut.size ** 0.5)) if lut.ndim == 1 else lut.shape[0]
-    lut_flat = jnp.asarray(lut).reshape(-1).astype(jnp.int32)
+    tab, n_planes, m00 = table_operands(lut, offset)
     bh, sq, d = q.shape
     bh_kv, sk, _ = k.shape
     rep = bh // bh_kv
@@ -309,13 +282,11 @@ def prepare_approx_attention(q, k, v, lut, offset, q_scale, k_scale, v_scale,
     hi = (1 << (bits - 1)) - 1
     # q tiles align to 8 sublanes, kv blocks to the 128-lane tile; small
     # sequences shrink the block instead of padding to the full default
-    bq = min(bq, _round_up(sq, 8))
-    bk = min(bk, _round_up(sk, 128))
-    dp = _round_up(d, 16)
-    inner_d = 16
-    inner_k = next(x for x in (32, 16, 8, 4, 2, 1) if bk % x == 0)
-    sq_p = _round_up(sq, bq)
-    sk_p = _round_up(sk, bk)
+    bq = min(bq, round_up(sq, 8))
+    bk = min(bk, round_up(sk, 128))
+    dp = round_up(d, 16)
+    sq_p = round_up(sq, bq)
+    sk_p = round_up(sk, bk)
     qf = jnp.asarray(q, jnp.float32)
     kf = jnp.asarray(k, jnp.float32)
     vf = jnp.asarray(v, jnp.float32)
@@ -334,11 +305,10 @@ def prepare_approx_attention(q, k, v, lut, offset, q_scale, k_scale, v_scale,
     sks = jnp.asarray(k_scale, jnp.float32).reshape(1)
     svs = jnp.asarray(v_scale, jnp.float32).reshape(1)
     score_scale, pv_scale = attn_scales(sqs, sks, svs, d, hi)
-    operands = (qf, kf, vf, lut_flat, rowinfo, sqs, sks, svs, score_scale,
-                pv_scale)
-    statics = dict(seq_k_real=sk, d_real=d, n_codes=n_codes, offset=offset,
-                   lo=lo, hi=hi, bq=bq, bk=bk, rep=rep, inner_d=inner_d,
-                   inner_k=inner_k)
+    operands = (qf, kf, vf, tab, rowinfo, sqs, sks, svs, score_scale,
+                pv_scale, m00)
+    statics = dict(seq_k_real=sk, d_real=d, n_planes=n_planes, offset=offset,
+                   lo=lo, hi=hi, bq=bq, bk=bk, rep=rep)
     return operands, statics
 
 
@@ -377,66 +347,38 @@ def approx_flash_attention(q, k, v, lut, offset, q_scale, k_scale, v_scale, *,
 # ---------------------------------------------------------------------------
 
 def _approx_paged_kernel(q_ref, k_ref, v_ref, lut_ref, info_ref, pt_ref,
-                         sq_ref, sk_ref, sv_ref, ss_ref, pvs_ref, o_ref, *,
-                         bq: int, bk: int, n_logical: int, d_real: int,
-                         n_codes: int, offset: int, lo: int, hi: int,
-                         causal: bool, window: int | None,
-                         softcap: float | None, inner_d: int, inner_k: int):
+                         sq_ref, sk_ref, sv_ref, ss_ref, pvs_ref, m00_ref,
+                         o_ref, *, bk: int, n_logical: int, **statics):
     """Paged twin of ``_approx_kernel``: ``k_ref``/``v_ref`` hold one KV
-    head's slice of the physical block pool, ``pt_ref`` the row's page
-    table; the loop body is the same ``_online_block`` with the
-    ``kv_blocks`` indirection. ``seq_k_real`` is always the full logical
-    extent (``n_logical * bk``) — pool blocks are whole by construction, so
-    there is no structural tail pad to correct; validity lives entirely in
-    ``kv_len``."""
-    qi = pl.program_id(1)
-    dp = q_ref.shape[-1]
-    lut = lut_ref[...]
-    m00 = lut[offset * n_codes + offset]
-    info = info_ref[...]
-    q_base, kv_start, kv_len = info[0, 0], info[0, 1], info[0, 2]
-    pt = pt_ref[...][0]                                        # (n_logical,)
+    head's slice of the physical block pool, ``pt_ref`` (SMEM) the page
+    tables; logical block ``ki`` of row ``b`` starts at
+    ``page_table[b, ki] * bk`` in the pool. ``seq_k_real`` is always the
+    full logical extent (``n_logical * bk``) — pool blocks are whole by
+    construction, so there is no structural tail pad to correct; validity
+    lives entirely in ``kv_len``."""
+    b = pl.program_id(0)
 
-    qf = q_ref[...][0].astype(jnp.float32)                     # (bq, dp)
-    qq = _quantize_sym(qf, sq_ref[0], lo, hi, offset)
-    q_pos = (q_base + qi * bq
-             + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+    def load_kv(ki):
+        start = pl.multiple_of(pt_ref[b, ki] * bk, bk)
+        return (k_ref[0, pl.ds(start, bk), :].astype(jnp.float32),
+                v_ref[0, pl.ds(start, bk), :].astype(jnp.float32))
 
-    k_all = k_ref[...][0]                                      # (P*bk, dp)
-    v_all = v_ref[...][0]
-
-    if causal:
-        n_kv_eff = causal_block_bound(q_base, qi, bq, bk, n_logical)
-    else:
-        n_kv_eff = n_logical
-
-    body = functools.partial(
-        _online_block, qq=qq, q_pos=q_pos, k_all=k_all, v_all=v_all, lut=lut,
-        m00=m00, sks=sk_ref[0], svs=sv_ref[0], score_scale=ss_ref[0],
-        pv_scale=pvs_ref[0], kv_start=kv_start, kv_len=kv_len, bq=bq, bk=bk,
-        seq_k_real=n_logical * bk, d_real=d_real, n_codes=n_codes,
-        offset=offset, lo=lo, hi=hi, causal=causal, window=window,
-        softcap=softcap, inner_d=inner_d, inner_k=inner_k, kv_blocks=pt)
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, dp), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_kv_eff, body, (m0, l0, acc0))
-    out = acc / jnp.maximum(l, 1e-30)[:, None]
-    o_ref[...] = out[None]
+    _attend(q_ref, lut_ref, info_ref, sq_ref, sk_ref, sv_ref, ss_ref, pvs_ref,
+            m00_ref, o_ref, load_kv, n_kv=n_logical, bk=bk,
+            seq_k_real=n_logical * bk, **statics)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "d_real", "n_codes", "offset", "lo", "hi", "causal", "window", "softcap",
-    "bq", "bk", "rep", "inner_d", "inner_k", "interpret"))
-def approx_flash_attention_paged_kernel(q, k_pool, v_pool, lut_flat, rowinfo,
+    "d_real", "n_planes", "offset", "lo", "hi", "causal", "window", "softcap",
+    "bq", "bk", "rep", "interpret"))
+def approx_flash_attention_paged_kernel(q, k_pool, v_pool, lut, rowinfo,
                                         page_table, sqs, sks, svs,
-                                        score_scale, pv_scale, *,
-                                        d_real: int, n_codes: int,
+                                        score_scale, pv_scale, m00, *,
+                                        d_real: int, n_planes: int,
                                         offset: int, lo: int, hi: int,
                                         causal: bool, window: int | None,
                                         softcap: float | None, bq: int,
-                                        bk: int, rep: int, inner_d: int,
-                                        inner_k: int,
+                                        bk: int, rep: int,
                                         interpret: bool | None = None):
     """Pre-padded paged entry: q (B*Hq, Sq_p, Dp) f32; ``k_pool``/``v_pool``
     (Hkv, P*bk, Dp) — the physical block pool, one row per KV head, blocks
@@ -449,32 +391,26 @@ def approx_flash_attention_paged_kernel(q, k_pool, v_pool, lut_flat, rowinfo,
     n_logical = page_table.shape[1]
     assert page_table.shape[0] == bh and rowinfo.shape == (bh, 3)
     assert sq_p % bq == 0 and pool_len % bk == 0, (sq_p, pool_len, bq, bk)
-    assert dp % inner_d == 0 and bk % inner_k == 0, (dp, inner_d, bk, inner_k)
-    grid = (bh, sq_p // bq)
-    scale_spec = pl.BlockSpec((1,), lambda b, i: (0,))
     return pl.pallas_call(
         functools.partial(_approx_paged_kernel, bq=bq, bk=bk,
                           n_logical=n_logical, d_real=d_real,
-                          n_codes=n_codes, offset=offset, lo=lo, hi=hi,
-                          causal=causal, window=window, softcap=softcap,
-                          inner_d=inner_d, inner_k=inner_k),
-        grid=grid,
+                          n_planes=n_planes, offset=offset, lo=lo, hi=hi,
+                          causal=causal, window=window, softcap=softcap),
+        grid=(bh, sq_p // bq),
         in_specs=[
             pl.BlockSpec((1, bq, dp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, pool_len, dp),
                          lambda b, i: ((b // rep) % hkv, 0, 0)),
             pl.BlockSpec((1, pool_len, dp),
                          lambda b, i: ((b // rep) % hkv, 0, 0)),
-            pl.BlockSpec((n_codes * n_codes,), lambda b, i: (0,)),
-            pl.BlockSpec((1, 3), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, n_logical), lambda b, i: (b, 0)),
-            scale_spec, scale_spec, scale_spec, scale_spec, scale_spec,
+            pl.BlockSpec(lut.shape, lambda b, i: (0, 0)),
+            SMEM, SMEM, SMEM, SMEM, SMEM, SMEM, SMEM, SMEM,
         ],
         out_specs=pl.BlockSpec((1, bq, dp), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, dp), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(q, k_pool, v_pool, lut_flat, rowinfo, page_table, sqs, sks, svs,
-      score_scale, pv_scale)
+    )(q, k_pool, v_pool, lut, rowinfo, page_table, sqs, sks, svs,
+      score_scale, pv_scale, m00)
 
 
 def prepare_approx_attention_paged(q, k_pool, v_pool, lut, offset, q_scale,
@@ -483,10 +419,9 @@ def prepare_approx_attention_paged(q, k_pool, v_pool, lut, offset, q_scale,
     """Shared padding/geometry/scale resolution for the paged kernel AND its
     jnp oracle (mirror of :func:`prepare_approx_attention`). The KV block
     size is fixed by the pool layout (``bk = pool block extent``), so only
-    q-side geometry adapts; the pool's head dim is padded to the gather
-    chunk exactly like the contiguous operands."""
-    n_codes = int(round(lut.size ** 0.5)) if lut.ndim == 1 else lut.shape[0]
-    lut_flat = jnp.asarray(lut).reshape(-1).astype(jnp.int32)
+    q-side geometry adapts; the pool's head dim is padded like the
+    contiguous operands."""
+    tab, n_planes, m00 = table_operands(lut, offset)
     bh, sq, d = q.shape
     hkv, n_phys, bk, _ = k_pool.shape
     page_table = jnp.asarray(page_table, jnp.int32)
@@ -495,11 +430,9 @@ def prepare_approx_attention_paged(q, k_pool, v_pool, lut, offset, q_scale,
     assert page_table.shape[0] == bh, (page_table.shape, bh)
     lo = -(1 << (bits - 1))
     hi = (1 << (bits - 1)) - 1
-    bq = min(bq, _round_up(sq, 8))
-    dp = _round_up(d, 16)
-    inner_d = 16
-    inner_k = next(x for x in (32, 16, 8, 4, 2, 1) if bk % x == 0)
-    sq_p = _round_up(sq, bq)
+    bq = min(bq, round_up(sq, 8))
+    dp = round_up(d, 16)
+    sq_p = round_up(sq, bq)
     qf = jnp.asarray(q, jnp.float32)
     kp = jnp.asarray(k_pool, jnp.float32).reshape(hkv, n_phys * bk, d)
     vp = jnp.asarray(v_pool, jnp.float32).reshape(hkv, n_phys * bk, d)
@@ -512,10 +445,10 @@ def prepare_approx_attention_paged(q, k_pool, v_pool, lut, offset, q_scale,
     sks = jnp.asarray(k_scale, jnp.float32).reshape(1)
     svs = jnp.asarray(v_scale, jnp.float32).reshape(1)
     score_scale, pv_scale = attn_scales(sqs, sks, svs, d, hi)
-    operands = (qf, kp, vp, lut_flat, rowinfo, page_table, sqs, sks, svs,
-                score_scale, pv_scale)
-    statics = dict(d_real=d, n_codes=n_codes, offset=offset, lo=lo, hi=hi,
-                   bq=bq, bk=bk, inner_d=inner_d, inner_k=inner_k)
+    operands = (qf, kp, vp, tab, rowinfo, page_table, sqs, sks, svs,
+                score_scale, pv_scale, m00)
+    statics = dict(d_real=d, n_planes=n_planes, offset=offset, lo=lo, hi=hi,
+                   bq=bq, bk=bk)
     return operands, statics
 
 

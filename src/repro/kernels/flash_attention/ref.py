@@ -43,26 +43,20 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "seq_k_real", "d_real", "n_codes",
-    "offset", "lo", "hi", "bq", "bk", "rep", "inner_d", "inner_k"))
-def _approx_ref_core(qp, kp, vp, lut_flat, info, sqs, sks, svs, score_scale,
-                     pv_scale, *, causal: bool, window: int | None,
-                     softcap: float | None, seq_k_real: int, d_real: int,
-                     n_codes: int, offset: int, lo: int, hi: int, bq: int,
-                     bk: int, rep: int, inner_d: int, inner_k: int):
+def _attend_rows(qp, lut, info, sqs, sks, svs, score_scale, pv_scale, m00,
+                 kv_of_row, *, n_kv: int, causal: bool, window: int | None,
+                 softcap: float | None, seq_k_real: int, d_real: int,
+                 n_planes: int, offset: int, lo: int, hi: int, bq: int,
+                 bk: int):
+    """Python (row, q-block) orchestration of ``_online_block``;
+    ``kv_of_row(b)`` -> the row's ``load_kv(ki)``."""
     from .approx import NEG_INF, _online_block, _quantize_sym, \
         causal_block_bound
 
     bh, sq_p, dp = qp.shape
-    sk_p = kp.shape[1]
-    n_kv = sk_p // bk
-    m00 = lut_flat[offset * n_codes + offset]
     out_rows = []
     for b in range(bh):
         q_base, kv_start, kv_len = info[b, 0], info[b, 1], info[b, 2]
-        k_all = kp[b // rep]
-        v_all = vp[b // rep]
         q_blocks = []
         for qi in range(sq_p // bq):
             qf = qp[b, qi * bq:(qi + 1) * bq].astype(jnp.float32)
@@ -74,13 +68,13 @@ def _approx_ref_core(qp, kp, vp, lut_flat, info, sqs, sks, svs, score_scale,
             else:
                 n_kv_eff = n_kv
             body = functools.partial(
-                _online_block, qq=qq, q_pos=q_pos, k_all=k_all, v_all=v_all,
-                lut=lut_flat, m00=m00, sks=sks[0], svs=svs[0],
+                _online_block, qq=qq, q_pos=q_pos, load_kv=kv_of_row(b),
+                lut=lut, m00=m00[0], sks=sks[0], svs=svs[0],
                 score_scale=score_scale[0], pv_scale=pv_scale[0],
                 kv_start=kv_start, kv_len=kv_len, bq=bq, bk=bk,
-                seq_k_real=seq_k_real, d_real=d_real, n_codes=n_codes,
+                seq_k_real=seq_k_real, d_real=d_real, n_planes=n_planes,
                 offset=offset, lo=lo, hi=hi, causal=causal, window=window,
-                softcap=softcap, inner_d=inner_d, inner_k=inner_k)
+                softcap=softcap)
             m0 = jnp.full((bq,), NEG_INF, jnp.float32)
             l0 = jnp.zeros((bq,), jnp.float32)
             acc0 = jnp.zeros((bq, dp), jnp.float32)
@@ -88,6 +82,31 @@ def _approx_ref_core(qp, kp, vp, lut_flat, info, sqs, sks, svs, score_scale,
             q_blocks.append(acc / jnp.maximum(l, 1e-30)[:, None])
         out_rows.append(jnp.concatenate(q_blocks, axis=0))
     return jnp.stack(out_rows)
+
+
+def _block_loader(k_all, v_all, bk: int, starts):
+    """``load_kv`` over whole (S, dp) arrays; ``starts(ki)`` -> first row."""
+    def load_kv(ki):
+        start = starts(ki)
+        dp = k_all.shape[-1]
+        return (jax.lax.dynamic_slice(k_all, (start, 0), (bk, dp)
+                                      ).astype(jnp.float32),
+                jax.lax.dynamic_slice(v_all, (start, 0), (bk, dp)
+                                      ).astype(jnp.float32))
+    return load_kv
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "window", "softcap", "seq_k_real", "d_real", "n_planes",
+    "offset", "lo", "hi", "bq", "bk", "rep"))
+def _approx_ref_core(qp, kp, vp, lut, info, sqs, sks, svs, score_scale,
+                     pv_scale, m00, *, rep: int, **statics):
+    bk = statics["bk"]
+    return _attend_rows(
+        qp, lut, info, sqs, sks, svs, score_scale, pv_scale, m00,
+        lambda b: _block_loader(kp[b // rep], vp[b // rep], bk,
+                                lambda ki: ki * bk),
+        n_kv=kp.shape[1] // bk, **statics)
 
 
 def approx_attention_ref(q, k, v, lut, offset, q_scale, k_scale, v_scale, *,
@@ -112,53 +131,25 @@ def approx_attention_ref(q, k, v, lut, offset, q_scale, k_scale, v_scale, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "d_real", "n_codes", "offset", "lo", "hi",
-    "bq", "bk", "rep", "inner_d", "inner_k"))
-def _approx_paged_ref_core(qp, kp, vp, lut_flat, info, page_table, sqs, sks,
-                           svs, score_scale, pv_scale, *, causal: bool,
-                           window: int | None, softcap: float | None,
-                           d_real: int, n_codes: int, offset: int, lo: int,
-                           hi: int, bq: int, bk: int, rep: int, inner_d: int,
-                           inner_k: int):
-    from .approx import NEG_INF, _online_block, _quantize_sym, \
-        causal_block_bound
-
-    bh, sq_p, dp = qp.shape
+    "causal", "window", "softcap", "d_real", "n_planes", "offset", "lo", "hi",
+    "bq", "bk", "rep"))
+def _approx_paged_ref_core(qp, kp, vp, lut, info, page_table, sqs, sks, svs,
+                           score_scale, pv_scale, m00, *, rep: int,
+                           **statics):
+    bk = statics["bk"]
     hkv = kp.shape[0]
     n_logical = page_table.shape[1]
-    m00 = lut_flat[offset * n_codes + offset]
-    out_rows = []
-    for b in range(bh):
-        q_base, kv_start, kv_len = info[b, 0], info[b, 1], info[b, 2]
-        k_all = kp[(b // rep) % hkv]
-        v_all = vp[(b // rep) % hkv]
+
+    def kv_of_row(b):
         pt = page_table[b]
-        q_blocks = []
-        for qi in range(sq_p // bq):
-            qf = qp[b, qi * bq:(qi + 1) * bq].astype(jnp.float32)
-            qq = _quantize_sym(qf, sqs[0], lo, hi, offset)
-            q_pos = (q_base + qi * bq
-                     + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
-            if causal:
-                n_kv_eff = causal_block_bound(q_base, qi, bq, bk, n_logical)
-            else:
-                n_kv_eff = n_logical
-            body = functools.partial(
-                _online_block, qq=qq, q_pos=q_pos, k_all=k_all, v_all=v_all,
-                lut=lut_flat, m00=m00, sks=sks[0], svs=svs[0],
-                score_scale=score_scale[0], pv_scale=pv_scale[0],
-                kv_start=kv_start, kv_len=kv_len, bq=bq, bk=bk,
-                seq_k_real=n_logical * bk, d_real=d_real, n_codes=n_codes,
-                offset=offset, lo=lo, hi=hi, causal=causal, window=window,
-                softcap=softcap, inner_d=inner_d, inner_k=inner_k,
-                kv_blocks=pt)
-            m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-            l0 = jnp.zeros((bq,), jnp.float32)
-            acc0 = jnp.zeros((bq, dp), jnp.float32)
-            m, l, acc = jax.lax.fori_loop(0, n_kv_eff, body, (m0, l0, acc0))
-            q_blocks.append(acc / jnp.maximum(l, 1e-30)[:, None])
-        out_rows.append(jnp.concatenate(q_blocks, axis=0))
-    return jnp.stack(out_rows)
+        return _block_loader(
+            kp[(b // rep) % hkv], vp[(b // rep) % hkv], bk,
+            lambda ki: jax.lax.dynamic_index_in_dim(
+                pt, ki, keepdims=False).astype(jnp.int32) * bk)
+
+    return _attend_rows(qp, lut, info, sqs, sks, svs, score_scale, pv_scale,
+                        m00, kv_of_row, n_kv=n_logical,
+                        seq_k_real=n_logical * bk, **statics)
 
 
 def approx_attention_paged_ref(q, k_pool, v_pool, lut, offset, q_scale,

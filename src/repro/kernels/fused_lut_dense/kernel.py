@@ -12,21 +12,20 @@ accumulator never leave VMEM. The weight side stays pre-quantized (codes are
 produced once per layer, not once per tile), matching the paper's "LUTs are
 populated once" regime.
 
-Structure mirrors ``lut_matmul``: the (2^b, 2^b) product table is pinned in
-VMEM for the whole grid; each (bm, bk) x (bk, bn) tile quantizes its
-activation block on the VPU, performs vectorized gathers in ``inner``-row
-sub-slices, and accumulates int32 into a persistent VMEM scratch tile. The
-final K step applies the affine dequant (per-tensor activation scale x
-per-channel weight scale row) and writes the float32 output tile — the only
-HBM store.
+The padded (R, L) product table is pinned in VMEM for the whole grid; each
+(bm, bk) x (bk, bn) tile streams its contraction in 128-wide pieces,
+quantizes each activation piece on the VPU and runs the shared LUT-GEMM core
+(:mod:`repro.kernels.lut_gather`: lane gathers of the table at the weight
+codes, then an exact one-hot int8 matmul on the MXU), accumulating int32
+into a persistent VMEM scratch tile. The final K step applies the affine
+dequant (per-tensor activation scale x per-channel weight scale row) and
+writes the float32 output tile — the only HBM store. Scalars (activation
+scale and zero-point, ``M[0, 0]``) live in SMEM.
 
-K-padding correction happens *in integer space* (``k_pad * LUT[off, off]``
+K-padding correction happens *in integer space* (``k_pad * M[0, 0]``
 subtracted from the accumulator before dequant) so padded shapes stay
 bit-exact vs the unpadded oracle — a float-space correction after dequant
 would not round-trip exactly.
-
-VMEM @ defaults (bm=bk=bn=128, 8-bit, inner=32): LUT 256 KiB + gather working
-set 128*32*128*4 B = 2 MiB + acc tile 64 KiB — comfortably inside 16 MiB.
 """
 from __future__ import annotations
 
@@ -35,46 +34,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.runtime import resolve_interpret
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lut_gather import LANES, SMEM, lut_gemm_streamed
+from repro.kernels.runtime import resolve_interpret
 
-def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, ws_ref, o_ref, acc_ref, *,
-            offset: int, n_codes: int, lo: int, hi: int, inner: int,
-            k_pad: int, emit_acc: bool):
-    k_step = pl.program_id(2)
 
-    @pl.when(k_step == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    xs = xs_ref[0]                                 # per-tensor activation scale
-    xz = xz_ref[0]                                 # activation zero-point (code)
-    x = x_ref[...].astype(jnp.float32)             # (bm, bk)
-    q = jnp.clip(jnp.round(x / xs + xz), lo, hi).astype(jnp.int32)
-    a = q - xz.astype(jnp.int32) + offset          # shifted code, index space
-    w = w_ref[...].astype(jnp.int32) + offset      # (bk, bn)
-    lut = lut_ref[...]                             # (n_codes * n_codes,)
-    bm, bk = a.shape
-    bn = w.shape[1]
-
-    def body(i, acc):
-        a_sl = jax.lax.dynamic_slice(a, (0, i * inner), (bm, inner))
-        w_sl = jax.lax.dynamic_slice(w, (i * inner, 0), (inner, bn))
-        idx = a_sl[:, :, None] * n_codes + w_sl[None, :, :]   # (bm, inner, bn)
-        prods = jnp.take(lut, idx.reshape(-1), unique_indices=False,
-                         indices_are_sorted=False).reshape(bm, inner, bn)
-        return acc + prods.sum(axis=1)
-
-    acc_ref[...] += jax.lax.fori_loop(0, bk // inner, body,
-                                      jnp.zeros((bm, bn), jnp.int32))
-
-    @pl.when(k_step == pl.num_programs(2) - 1)
+def _finish(acc_ref, o_ref, m00_ref, scale, *, k_pad: int, emit_acc: bool):
+    """Last K step: integer-space K-pad correction, then the raw accumulator
+    (``emit_acc``) or one combined-scale dequant."""
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _dequant():
         acc = acc_ref[...]
         if k_pad:  # padded k entries each contributed LUT[off, off] = M[0, 0]
-            acc = acc - k_pad * lut[offset * n_codes + offset]
+            acc = acc - k_pad * m00_ref[0]
         if emit_acc:
             # mesh contraction sharding: partial int32 accumulators leave the
             # kernel, psum across K shards, dequant once after the collective
@@ -82,135 +55,135 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, ws_ref, o_ref, acc_ref, *,
         else:
             # one combined-scale multiply: a * xs * ws chains get reassociated
             # by the XLA simplifier under shard_map, breaking bit-exactness
-            o_ref[...] = acc.astype(jnp.float32) * (xs * ws_ref[...])
+            o_ref[...] = acc.astype(jnp.float32) * scale()
 
 
-def _bwd_kernel(a_ref, b_ref, lut_ref, as_ref, bs_ref, o_ref, acc_ref, *,
-                offset: int, n_codes: int, lo: int, hi: int, inner: int,
-                k_pad: int, emit_acc: bool):
+def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, m00_ref, ws_ref, o_ref,
+            acc_ref, *, offset: int, lo: int, hi: int, k_pad: int,
+            n_planes: int, emit_acc: bool):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    xs = xs_ref[0]                                 # per-tensor activation scale
+    xz = xz_ref[0]                                 # activation zero-point (code)
+    bm, bk = x_ref.shape
+    bn = w_ref.shape[1]
+
+    def load_a(k0):                                # shifted codes, index space
+        x = x_ref[:, pl.ds(k0, LANES)].astype(jnp.float32)
+        q = jnp.clip(jnp.round(x / xs + xz), lo, hi).astype(jnp.int32)
+        return q - xz.astype(jnp.int32) + offset
+
+    def load_b(k0):
+        return w_ref[pl.ds(k0, LANES), :].astype(jnp.int32) + offset
+
+    acc_ref[...] += lut_gemm_streamed(load_a, load_b, bk // LANES, lut_ref,
+                                      m=bm, n=bn, n_planes=n_planes)
+    _finish(acc_ref, o_ref, m00_ref, lambda: xs * ws_ref[...], k_pad=k_pad,
+            emit_acc=emit_acc)
+
+
+def _bwd_kernel(a_ref, b_ref, lut_ref, as_ref, bs_ref, m00_ref, o_ref,
+                acc_ref, *, offset: int, lo: int, hi: int, k_pad: int,
+                n_planes: int, emit_acc: bool):
     """Backward flavor: BOTH operands arrive as float residuals and are
     quantized in-kernel with per-tensor *symmetric* scales (zero-point 0 —
     gradients are zero-centred, and a zp-free quantizer keeps the combined
     dequant a single scale multiply). Everything downstream is the forward
-    kernel verbatim: shifted-code LUT gathers, int32 accumulate, integer-space
-    K-pad correction, one combined-scale dequant."""
-    k_step = pl.program_id(2)
-
-    @pl.when(k_step == 0)
+    kernel verbatim: shifted-code LUT-GEMM core, int32 accumulate,
+    integer-space K-pad correction, one combined-scale dequant."""
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     sa = as_ref[0]                                 # per-tensor symmetric scales
     sb = bs_ref[0]
-    af = a_ref[...].astype(jnp.float32)            # (bm, bk)
-    bf = b_ref[...].astype(jnp.float32)            # (bk, bn)
-    a = jnp.clip(jnp.round(af / sa), lo, hi).astype(jnp.int32) + offset
-    b = jnp.clip(jnp.round(bf / sb), lo, hi).astype(jnp.int32) + offset
-    lut = lut_ref[...]                             # (n_codes * n_codes,)
-    bm, bk = a.shape
-    bn = b.shape[1]
+    bm, bk = a_ref.shape
+    bn = b_ref.shape[1]
 
-    def body(i, acc):
-        a_sl = jax.lax.dynamic_slice(a, (0, i * inner), (bm, inner))
-        b_sl = jax.lax.dynamic_slice(b, (i * inner, 0), (inner, bn))
-        idx = a_sl[:, :, None] * n_codes + b_sl[None, :, :]   # (bm, inner, bn)
-        prods = jnp.take(lut, idx.reshape(-1), unique_indices=False,
-                         indices_are_sorted=False).reshape(bm, inner, bn)
-        return acc + prods.sum(axis=1)
+    def load_a(k0):
+        af = a_ref[:, pl.ds(k0, LANES)].astype(jnp.float32)
+        return jnp.clip(jnp.round(af / sa), lo, hi).astype(jnp.int32) + offset
 
-    acc_ref[...] += jax.lax.fori_loop(0, bk // inner, body,
-                                      jnp.zeros((bm, bn), jnp.int32))
+    def load_b(k0):
+        bf = b_ref[pl.ds(k0, LANES), :].astype(jnp.float32)
+        return jnp.clip(jnp.round(bf / sb), lo, hi).astype(jnp.int32) + offset
 
-    @pl.when(k_step == pl.num_programs(2) - 1)
-    def _dequant():
-        acc = acc_ref[...]
-        if k_pad:  # zero pads quantize to code 0 -> LUT[off, off] = M[0, 0]
-            acc = acc - k_pad * lut[offset * n_codes + offset]
-        if emit_acc:
-            o_ref[...] = acc
-        else:
-            o_ref[...] = acc.astype(jnp.float32) * (sa * sb)
+    acc_ref[...] += lut_gemm_streamed(load_a, load_b, bk // LANES, lut_ref,
+                                      m=bm, n=bn, n_planes=n_planes)
+    _finish(acc_ref, o_ref, m00_ref, lambda: sa * sb, k_pad=k_pad,
+            emit_acc=emit_acc)
 
 
-@functools.partial(jax.jit, static_argnames=("offset", "n_codes", "lo", "hi",
-                                             "k_pad", "bm", "bk", "bn",
-                                             "inner", "interpret", "emit_acc"))
-def fused_lut_bwd_kernel(a: jnp.ndarray, b: jnp.ndarray,
-                         lut_flat: jnp.ndarray, a_scale: jnp.ndarray,
-                         b_scale: jnp.ndarray, *, offset: int, n_codes: int,
-                         lo: int, hi: int, k_pad: int = 0, bm: int = 128,
-                         bk: int = 128, bn: int = 128, inner: int = 32,
+def _gemm_call(kernel, lhs, rhs, operands, specs, *, bm: int, bk: int,
+               bn: int, interpret, emit_acc: bool):
+    """The shared ``pallas_call``: (i, j, k) grid over (M, N, K) tiles, the
+    whole padded table resident, int32 accumulator scratch."""
+    M, K = lhs.shape
+    _, N = rhs.shape
+    assert M % bm == 0 and K % bk == 0 and N % bn == 0 and bk % LANES == 0, (
+        f"shape {(M, K, N)} not divisible by tile {(bm, bk, bn)}")
+    lut = operands[0]
+    return pl.pallas_call(
+        kernel,
+        grid=(M // bm, N // bn, K // bk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec(lut.shape, lambda i, j, k: (0, 0)),
+            *specs,
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((M, N),
+                                       jnp.int32 if emit_acc else jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        interpret=resolve_interpret(interpret),
+    )(lhs, rhs, *operands)
+
+
+@functools.partial(jax.jit, static_argnames=("offset", "lo", "hi", "k_pad",
+                                             "n_planes", "bm", "bk", "bn",
+                                             "interpret", "emit_acc"))
+def fused_lut_bwd_kernel(a: jnp.ndarray, b: jnp.ndarray, lut: jnp.ndarray,
+                         a_scale: jnp.ndarray, b_scale: jnp.ndarray,
+                         m00: jnp.ndarray, *, offset: int, lo: int, hi: int,
+                         k_pad: int = 0, n_planes: int = 4, bm: int = 128,
+                         bk: int = 128, bn: int = 128,
                          interpret: bool | None = None,
                          emit_acc: bool = False) -> jnp.ndarray:
     """a: (M, K) float; b: (K, N) float; both quantized in-kernel with the
-    per-tensor symmetric scales ``a_scale``/``b_scale`` (shape-(1,) f32).
+    per-tensor symmetric scales ``a_scale``/``b_scale`` (shape-(1,) f32);
+    lut: the (R, L) padded table; m00: shape-(1,) int32 ``LUT[off, off]``.
     Returns (M, N) float32 — or the raw int32 accumulator with
     ``emit_acc=True`` (the sharded contraction route psums those partials
     and dequantizes once after the collective)."""
-    M, K = a.shape
-    _, N = b.shape
-    bm, bk, bn = min(bm, M), min(bk, K), min(bn, N)
-    inner = min(inner, bk)
-    assert M % bm == 0 and K % bk == 0 and N % bn == 0 and bk % inner == 0, (
-        f"shape {(M, K, N)} not divisible by tile {(bm, bk, bn)}/{inner}")
-    grid = (M // bm, N // bn, K // bk)
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, offset=offset, n_codes=n_codes, lo=lo,
-                          hi=hi, inner=inner, k_pad=k_pad, emit_acc=emit_acc),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((n_codes * n_codes,), lambda i, j, k: (0,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N),
-                                       jnp.int32 if emit_acc else jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=resolve_interpret(interpret),
-    )(a, b, lut_flat, a_scale, b_scale)
+    return _gemm_call(
+        functools.partial(_bwd_kernel, offset=offset, lo=lo, hi=hi,
+                          k_pad=k_pad, n_planes=n_planes, emit_acc=emit_acc),
+        a, b, (lut, a_scale, b_scale, m00), [SMEM, SMEM, SMEM],
+        bm=bm, bk=bk, bn=bn, interpret=interpret, emit_acc=emit_acc)
 
 
-@functools.partial(jax.jit, static_argnames=("offset", "n_codes", "lo", "hi",
-                                             "k_pad", "bm", "bk", "bn",
-                                             "inner", "interpret", "emit_acc"))
-def fused_lut_dense_kernel(x: jnp.ndarray, wq: jnp.ndarray,
-                           lut_flat: jnp.ndarray, x_scale: jnp.ndarray,
-                           x_zp: jnp.ndarray, w_scale_row: jnp.ndarray, *,
-                           offset: int, n_codes: int, lo: int, hi: int,
-                           k_pad: int = 0, bm: int = 128, bk: int = 128,
-                           bn: int = 128, inner: int = 32,
-                           interpret: bool | None = None,
+@functools.partial(jax.jit, static_argnames=("offset", "lo", "hi", "k_pad",
+                                             "n_planes", "bm", "bk", "bn",
+                                             "interpret", "emit_acc"))
+def fused_lut_dense_kernel(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
+                           x_scale: jnp.ndarray, x_zp: jnp.ndarray,
+                           m00: jnp.ndarray, w_scale_row: jnp.ndarray, *,
+                           offset: int, lo: int, hi: int, k_pad: int = 0,
+                           n_planes: int = 4, bm: int = 128, bk: int = 128,
+                           bn: int = 128, interpret: bool | None = None,
                            emit_acc: bool = False) -> jnp.ndarray:
-    """x: (M, K) float; wq: (K, N) shifted int weight codes;
-    lut_flat: (n_codes**2,) int32; x_scale/x_zp: shape-(1,) f32;
-    w_scale_row: (1, N) f32. Returns (M, N) float32 — or the raw (M, N)
-    int32 accumulator with ``emit_acc=True`` (sharded contraction: the
-    caller psums partials across K shards and dequantizes after)."""
-    M, K = x.shape
-    _, N = wq.shape
-    bm, bk, bn = min(bm, M), min(bk, K), min(bn, N)
-    inner = min(inner, bk)
-    assert M % bm == 0 and K % bk == 0 and N % bn == 0 and bk % inner == 0, (
-        f"shape {(M, K, N)} not divisible by tile {(bm, bk, bn)}/{inner}")
-    grid = (M // bm, N // bn, K // bk)
-    return pl.pallas_call(
-        functools.partial(_kernel, offset=offset, n_codes=n_codes, lo=lo,
-                          hi=hi, inner=inner, k_pad=k_pad, emit_acc=emit_acc),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((n_codes * n_codes,), lambda i, j, k: (0,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N),
-                                       jnp.int32 if emit_acc else jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=resolve_interpret(interpret),
-    )(x, wq, lut_flat, x_scale, x_zp, w_scale_row)
+    """x: (M, K) float; wq: (K, N) shifted int weight codes; lut: the (R, L)
+    padded table; x_scale/x_zp: shape-(1,) f32; m00: shape-(1,) int32
+    ``LUT[off, off]``; w_scale_row: (1, N) f32. Returns (M, N) float32 — or
+    the raw (M, N) int32 accumulator with ``emit_acc=True`` (sharded
+    contraction: the caller psums partials across K shards and dequantizes
+    after)."""
+    return _gemm_call(
+        functools.partial(_kernel, offset=offset, lo=lo, hi=hi, k_pad=k_pad,
+                          n_planes=n_planes, emit_acc=emit_acc),
+        x, wq, (lut, x_scale, x_zp, m00, w_scale_row),
+        [SMEM, SMEM, SMEM, pl.BlockSpec((1, bn), lambda i, j, k: (0, j))],
+        bm=bm, bk=bk, bn=bn, interpret=interpret, emit_acc=emit_acc)

@@ -14,13 +14,30 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.lut_gather import round_up, table_operands
+
 from .kernel import fused_lut_bwd_kernel, fused_lut_dense_kernel
+
+
+def _tiles(M: int, K: int, N: int, bm: int, bk: int):
+    """Tile sizes and the padding that makes every dim a tile multiple.
+
+    M tiles cap at 128 and shrink to the 8-row multiple above a short M
+    (decode batches); N tiles are the 128-lane width of the LUT-GEMM core's
+    gathers; K pads to 128 and runs as one grid step when the whole row
+    strip fits VMEM comfortably, otherwise in a k-tile that divides it."""
+    bm = min(bm, 128, round_up(M, 8))
+    bn = 128
+    pm, pk, pn = (-M) % bm, (-K) % 128, (-N) % bn
+    kp = K + pk
+    bk = kp if kp <= 512 else (bk if kp % bk == 0 else 128)
+    return (bm, bk, bn), (pm, pk, pn)
 
 
 def fused_lut_dense(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
                     offset: int, x_scale, x_zp, w_scale, *, bits: int = 8,
-                    bm: int = 128, bk: int = 256, bn: int = 128,
-                    inner: int = 32, interpret: bool | None = None,
+                    bm: int = 128, bk: int = 256,
+                    interpret: bool | None = None,
                     emit_acc: bool = False) -> jnp.ndarray:
     """Fused approximate dense forward.
 
@@ -36,8 +53,7 @@ def fused_lut_dense(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
     mesh contraction-sharded route psums these partials across K shards and
     dequantizes once after the collective.
     """
-    n_codes = int(round(lut.size ** 0.5)) if lut.ndim == 1 else lut.shape[0]
-    lut_flat = lut.reshape(-1)
+    tab, n_planes, m00 = table_operands(lut, offset)
     M, K = x.shape
     _, N = wq.shape
     lo = -(1 << (bits - 1))
@@ -46,31 +62,22 @@ def fused_lut_dense(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
     xz = jnp.asarray(x_zp, jnp.float32).reshape(1)
     ws = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32).reshape(1, -1),
                           (1, N))
-    # M/N tiles cap at 128 so the padding granularity below always matches
-    # the tile the kernel picks (K is the streamed dim and handled apart)
-    bm, bn = min(bm, 128), min(bn, 128)
-    pm = (-M) % min(bm, 128)
-    pk = (-K) % 128
-    pn = (-N) % min(bn, 128)
+    (bm, bk, bn), (pm, pk, pn) = _tiles(M, K, N, bm, bk)
     if pm or pk or pn:
         x = jnp.pad(x, ((0, pm), (0, pk)))
         wq = jnp.pad(wq, ((0, pk), (0, pn)))
         ws = jnp.pad(ws, ((0, 0), (0, pn)))
-    # single K grid step when the whole row strip fits VMEM comfortably;
-    # otherwise a k-tile that divides the (128-multiple) padded K
-    kp = K + pk
-    bk = kp if kp <= 512 else (bk if kp % bk == 0 else 128)
-    out = fused_lut_dense_kernel(x, wq, lut_flat, xs, xz, ws,
-                                 offset=offset, n_codes=n_codes, lo=lo, hi=hi,
-                                 k_pad=pk, bm=bm, bk=bk, bn=bn, inner=inner,
-                                 interpret=interpret, emit_acc=emit_acc)
+    out = fused_lut_dense_kernel(x, wq, tab, xs, xz, m00, ws, offset=offset,
+                                 lo=lo, hi=hi, k_pad=pk, n_planes=n_planes,
+                                 bm=bm, bk=bk, bn=bn, interpret=interpret,
+                                 emit_acc=emit_acc)
     return out[:M, :N]
 
 
 def fused_lut_bwd(a: jnp.ndarray, b: jnp.ndarray, lut: jnp.ndarray,
                   offset: int, a_scale, b_scale, *, bits: int = 8,
-                  bm: int = 128, bk: int = 256, bn: int = 128,
-                  inner: int = 32, interpret: bool | None = None,
+                  bm: int = 128, bk: int = 256,
+                  interpret: bool | None = None,
                   emit_acc: bool = False) -> jnp.ndarray:
     """Fused approximate backward GEMM: quantize BOTH float operands
     in-kernel (per-tensor symmetric, zero-point 0), LUT-gather GEMM, int32
@@ -84,25 +91,19 @@ def fused_lut_bwd(a: jnp.ndarray, b: jnp.ndarray, lut: jnp.ndarray,
     the forward. ``emit_acc=True`` returns the raw int32 accumulator for the
     mesh contraction-sharded route (psum, correct once, dequant after).
     """
-    n_codes = int(round(lut.size ** 0.5)) if lut.ndim == 1 else lut.shape[0]
-    lut_flat = lut.reshape(-1)
+    tab, n_planes, m00 = table_operands(lut, offset)
     M, K = a.shape
     _, N = b.shape
     lo = -(1 << (bits - 1))
     hi = (1 << (bits - 1)) - 1
     sa = jnp.asarray(a_scale, jnp.float32).reshape(1)
     sb = jnp.asarray(b_scale, jnp.float32).reshape(1)
-    bm, bn = min(bm, 128), min(bn, 128)
-    pm = (-M) % min(bm, 128)
-    pk = (-K) % 128
-    pn = (-N) % min(bn, 128)
+    (bm, bk, bn), (pm, pk, pn) = _tiles(M, K, N, bm, bk)
     if pm or pk or pn:
         a = jnp.pad(a, ((0, pm), (0, pk)))
         b = jnp.pad(b, ((0, pk), (0, pn)))
-    kp = K + pk
-    bk = kp if kp <= 512 else (bk if kp % bk == 0 else 128)
-    out = fused_lut_bwd_kernel(a, b, lut_flat, sa, sb, offset=offset,
-                               n_codes=n_codes, lo=lo, hi=hi, k_pad=pk,
-                               bm=bm, bk=bk, bn=bn, inner=inner,
-                               interpret=interpret, emit_acc=emit_acc)
+    out = fused_lut_bwd_kernel(a, b, tab, sa, sb, m00, offset=offset, lo=lo,
+                               hi=hi, k_pad=pk, n_planes=n_planes, bm=bm,
+                               bk=bk, bn=bn, interpret=interpret,
+                               emit_acc=emit_acc)
     return out[:M, :N]

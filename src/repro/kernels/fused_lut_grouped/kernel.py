@@ -14,12 +14,13 @@ buffer at ``moe_capacity`` 1.25+ with realistic (skewed) routing is mostly
 dead rows.
 
 Inside a live block the body is the established fused recipe, verbatim from
-``fused_lut_dense``: per-tensor in-kernel activation quantization, shifted
-code LUT gathers in ``inner``-row sub-slices, int32 accumulate into a
-persistent VMEM scratch tile, integer-space K-pad correction, and ONE
-combined-scale dequant (``acc * (xs * ws)``) on the final K step. int32 adds
-are associative and the k-chunk order matches the dense kernel's, so each
-live row is bit-identical to the per-expert ``fused_lut_dense`` call.
+``fused_lut_dense``: per-tensor in-kernel activation quantization of each
+128-wide contraction piece, the shared LUT-GEMM core
+(:mod:`repro.kernels.lut_gather`), int32 accumulate into a persistent VMEM
+scratch tile, integer-space K-pad correction, and ONE combined-scale
+dequant (``acc * (xs * ws)``) on the final K step. int32 adds are
+associative, so each live row is bit-identical to the per-expert
+``fused_lut_dense`` call. Scalars and ``groupinfo`` live in SMEM.
 
 Dead rows (``row >= row_count``) write exactly 0.0. This is a deliberate
 contract, not just hygiene: a zero *input* row still produces
@@ -42,12 +43,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lut_gather import LANES, SMEM, lut_gemm_streamed
 from repro.kernels.runtime import resolve_interpret
 
 
-def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, ws_ref, info_ref,
-            o_ref, acc_ref, *, offset: int, n_codes: int, lo: int, hi: int,
-            inner: int, k_pad: int, emit_acc: bool):
+def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, m00_ref, ws_ref, info_ref,
+            o_ref, acc_ref, *, offset: int, lo: int, hi: int, k_pad: int,
+            n_planes: int, emit_acc: bool):
+    g = pl.program_id(0)
     m_step = pl.program_id(1)
     k_step = pl.program_id(3)
 
@@ -55,8 +58,8 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, ws_ref, info_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bm = acc_ref.shape[0]
-    count = info_ref[0, 1]                 # live rows in this group
+    bm, bn = acc_ref.shape
+    count = info_ref[g, 1]                 # live rows in this group
     live = count - m_step * bm             # live rows at/after this row-block
 
     @pl.when(live > 0)
@@ -66,24 +69,18 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, ws_ref, info_ref,
         # quantize + gather work (the ragged-dispatch win)
         xs = xs_ref[0]                             # per-tensor activation scale
         xz = xz_ref[0]                             # activation zero-point (code)
-        x = x_ref[...].astype(jnp.float32)         # (bm, bk)
-        q = jnp.clip(jnp.round(x / xs + xz), lo, hi).astype(jnp.int32)
-        a = q - xz.astype(jnp.int32) + offset      # shifted code, index space
-        w = w_ref[0].astype(jnp.int32) + offset    # (bk, bn): expert g % E
-        lut = lut_ref[...]                         # (n_codes * n_codes,)
-        bm_, bk = a.shape
-        bn = w.shape[1]
 
-        def body(i, acc):
-            a_sl = jax.lax.dynamic_slice(a, (0, i * inner), (bm_, inner))
-            w_sl = jax.lax.dynamic_slice(w, (i * inner, 0), (inner, bn))
-            idx = a_sl[:, :, None] * n_codes + w_sl[None, :, :]
-            prods = jnp.take(lut, idx.reshape(-1), unique_indices=False,
-                             indices_are_sorted=False).reshape(bm_, inner, bn)
-            return acc + prods.sum(axis=1)
+        def load_a(k0):                            # shifted codes, index space
+            x = x_ref[:, pl.ds(k0, LANES)].astype(jnp.float32)
+            q = jnp.clip(jnp.round(x / xs + xz), lo, hi).astype(jnp.int32)
+            return q - xz.astype(jnp.int32) + offset
 
-        acc_ref[...] += jax.lax.fori_loop(0, bk // inner, body,
-                                          jnp.zeros((bm_, bn), jnp.int32))
+        def load_b(k0):                            # expert g % E
+            return w_ref[0, pl.ds(k0, LANES), :].astype(jnp.int32) + offset
+
+        acc_ref[...] += lut_gemm_streamed(load_a, load_b,
+                                          x_ref.shape[1] // LANES, lut_ref,
+                                          m=bm, n=bn, n_planes=n_planes)
 
     @pl.when(k_step == pl.num_programs(3) - 1)
     def _dequant():
@@ -92,7 +89,7 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, ws_ref, info_ref,
             # applied unconditionally: dead row-blocks never accumulated, so
             # their value here is garbage either way — the row mask below is
             # what guarantees they emit exactly zero
-            acc = acc - k_pad * lut_ref[offset * n_codes + offset]
+            acc = acc - k_pad * m00_ref[0]
         row = m_step * bm + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
         if emit_acc:
             # contraction sharding: masked int32 partials leave the kernel;
@@ -107,49 +104,47 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, ws_ref, info_ref,
                 row < count, acc.astype(jnp.float32) * (xs * ws_ref[0]), 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("offset", "n_codes", "lo", "hi",
-                                             "k_pad", "cp", "bm", "bk", "bn",
-                                             "inner", "interpret", "emit_acc"))
-def fused_lut_grouped_kernel(x: jnp.ndarray, wq: jnp.ndarray,
-                             lut_flat: jnp.ndarray, x_scale: jnp.ndarray,
-                             x_zp: jnp.ndarray, w_scale: jnp.ndarray,
-                             info: jnp.ndarray, *, offset: int, n_codes: int,
-                             lo: int, hi: int, cp: int, k_pad: int = 0,
-                             bm: int = 128, bk: int = 128, bn: int = 128,
-                             inner: int = 32, interpret: bool | None = None,
+@functools.partial(jax.jit, static_argnames=("offset", "lo", "hi", "k_pad",
+                                             "n_planes", "cp", "bm", "bk",
+                                             "interpret", "emit_acc"))
+def fused_lut_grouped_kernel(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
+                             x_scale: jnp.ndarray, x_zp: jnp.ndarray,
+                             m00: jnp.ndarray, w_scale: jnp.ndarray,
+                             info: jnp.ndarray, *, offset: int, lo: int,
+                             hi: int, cp: int, k_pad: int = 0,
+                             n_planes: int = 4, bm: int = 128, bk: int = 128,
+                             interpret: bool | None = None,
                              emit_acc: bool = False) -> jnp.ndarray:
     """x: (G * cp, K) float rows, group g owning rows [g*cp, (g+1)*cp);
     wq: (E, K, N) shifted int weight codes (group g uses expert g % E);
-    lut_flat: (n_codes**2,) int32; x_scale/x_zp: shape-(1,) f32;
-    w_scale: (E, 1, N) f32; info: (G, 2) int32 ``[row_base, row_count]``.
+    lut: the (R, L) padded table; x_scale/x_zp: shape-(1,) f32; m00:
+    shape-(1,) int32 ``LUT[off, off]``; w_scale: (E, 1, N) f32; info: (G, 2)
+    int32 ``[row_base, row_count]``. ``K`` and ``N`` are multiples of 128.
     Returns (G * cp, N) float32 with rows >= row_count exactly 0.0 — or the
     raw int32 accumulator (dead rows zeroed) with ``emit_acc=True``."""
     Gm, K = x.shape
     E, _, N = wq.shape
     G = Gm // cp
-    bm, bk, bn = min(bm, cp), min(bk, K), min(bn, N)
-    inner = min(inner, bk)
+    bn = LANES
     assert Gm == G * cp and G % E == 0, (Gm, cp, E)
-    assert cp % bm == 0 and K % bk == 0 and N % bn == 0 and bk % inner == 0, (
-        f"shape {(cp, K, N)} not divisible by tile {(bm, bk, bn)}/{inner}")
+    assert cp % bm == 0 and K % bk == 0 and N % bn == 0 and bk % LANES == 0, (
+        f"shape {(cp, K, N)} not divisible by tile {(bm, bk, bn)}")
     mblocks = cp // bm
-    grid = (G, mblocks, N // bn, K // bk)
     return pl.pallas_call(
-        functools.partial(_kernel, offset=offset, n_codes=n_codes, lo=lo,
-                          hi=hi, inner=inner, k_pad=k_pad, emit_acc=emit_acc),
-        grid=grid,
+        functools.partial(_kernel, offset=offset, lo=lo, hi=hi, k_pad=k_pad,
+                          n_planes=n_planes, emit_acc=emit_acc),
+        grid=(G, mblocks, N // bn, K // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda g, m, n, k: (g * mblocks + m, k)),
             pl.BlockSpec((1, bk, bn), lambda g, m, n, k: (g % E, k, n)),
-            pl.BlockSpec((n_codes * n_codes,), lambda g, m, n, k: (0,)),
-            pl.BlockSpec((1,), lambda g, m, n, k: (0,)),
-            pl.BlockSpec((1,), lambda g, m, n, k: (0,)),
+            pl.BlockSpec(lut.shape, lambda g, m, n, k: (0, 0)),
+            SMEM, SMEM, SMEM,
             pl.BlockSpec((1, 1, bn), lambda g, m, n, k: (g % E, 0, n)),
-            pl.BlockSpec((1, 2), lambda g, m, n, k: (g, 0)),
+            SMEM,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda g, m, n, k: (g * mblocks + m, n)),
         out_shape=jax.ShapeDtypeStruct((Gm, N),
                                        jnp.int32 if emit_acc else jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=resolve_interpret(interpret),
-    )(x, wq, lut_flat, x_scale, x_zp, w_scale, info)
+    )(x, wq, lut, x_scale, x_zp, m00, w_scale, info)

@@ -15,14 +15,15 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.lut_gather import LANES, table_operands
+
 from .kernel import fused_lut_grouped_kernel
 
 
 def fused_lut_grouped(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
                       offset: int, x_scale, x_zp, w_scale,
                       counts: jnp.ndarray, *, bits: int = 8, bm: int = 128,
-                      bk: int = 256, bn: int = 128, inner: int = 32,
-                      interpret: bool | None = None,
+                      bk: int = 256, interpret: bool | None = None,
                       emit_acc: bool = False) -> jnp.ndarray:
     """Ragged grouped approximate GEMM over MoE capacity buffers.
 
@@ -41,8 +42,7 @@ def fused_lut_grouped(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
     zeroed; tile padding corrected in integer space) for the mesh
     contraction-sharded route.
     """
-    n_codes = int(round(lut.size ** 0.5)) if lut.ndim == 1 else lut.shape[0]
-    lut_flat = lut.reshape(-1)
+    tab, n_planes, m00 = table_operands(lut, offset)
     G, C, K = x.shape
     E, _, N = wq.shape
     assert G % E == 0, f"groups {G} not a multiple of experts {E}"
@@ -52,12 +52,12 @@ def fused_lut_grouped(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
     xz = jnp.asarray(x_zp, jnp.float32).reshape(1)
     ws = jnp.broadcast_to(
         jnp.asarray(w_scale, jnp.float32).reshape(E, 1, -1), (E, 1, N))
-    bm, bn = min(bm, 128), min(bn, 128)
+    bm = min(bm, 128)
     if C < bm:  # keep skip granularity on short capacity buffers
         bm = max(8, -(-C // 8) * 8)
     pc = (-C) % bm
-    pk = (-K) % 128
-    pn = (-N) % min(bn, 128)
+    pk = (-K) % LANES
+    pn = (-N) % LANES
     if pc or pk:
         x = jnp.pad(x, ((0, 0), (0, pc), (0, pk)))
     if pk or pn:
@@ -67,13 +67,12 @@ def fused_lut_grouped(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
     kp = K + pk
     # single K grid step when the whole row strip fits VMEM comfortably;
     # otherwise a k-tile that divides the (128-multiple) padded K
-    bk = kp if kp <= 512 else (bk if kp % bk == 0 else 128)
+    bk = kp if kp <= 512 else (bk if kp % bk == 0 else LANES)
     info = jnp.stack(
         [jnp.arange(G, dtype=jnp.int32) * cp,
          jnp.clip(counts.astype(jnp.int32), 0, C)], axis=1)
     out = fused_lut_grouped_kernel(
-        x.reshape(G * cp, kp), wq, lut_flat, xs, xz, ws, info,
-        offset=offset, n_codes=n_codes, lo=lo, hi=hi, k_pad=pk, cp=cp,
-        bm=bm, bk=bk, bn=bn, inner=inner, interpret=interpret,
-        emit_acc=emit_acc)
+        x.reshape(G * cp, kp), wq, tab, xs, xz, m00, ws, info,
+        offset=offset, lo=lo, hi=hi, k_pad=pk, cp=cp, n_planes=n_planes,
+        bm=bm, bk=bk, interpret=interpret, emit_acc=emit_acc)
     return out.reshape(G, cp, N + pn)[:, :C, :N]

@@ -3,38 +3,30 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.lut_gather import lut_planes, pad_lut, round_up
+
 from .kernel import lut_matmul_kernel
 
 
-def _pick_tile(dim: int, pref: int) -> int:
-    for t in (pref, 64, 32, 16, 8, 4, 2, 1):
-        if t <= pref and dim % t == 0:
-            return t
-    return 1
-
-
 def lut_matmul(a: jnp.ndarray, w: jnp.ndarray, lut: jnp.ndarray, offset: int,
-               *, bm: int = 128, bk: int = 128, bn: int = 128,
-               interpret: bool | None = None) -> jnp.ndarray:
+               *, interpret: bool | None = None) -> jnp.ndarray:
     """LUT-gather GEMM with automatic tile selection / zero-padding.
 
     ``lut`` may be (n_codes, n_codes) or flattened. Padding uses code 0, whose
     LUT row/col contributes ``LUT[off, off]`` per padded k — subtracted after.
     """
-    n_codes = int(round(len(lut.reshape(-1)) ** 0.5)) if lut.ndim == 1 else lut.shape[0]
-    lut_flat = lut.reshape(-1)
+    tab, _ = pad_lut(lut)
     M, K = a.shape
     _, N = w.shape
-    # pad every dim up to a multiple of its preferred tile
-    pm = (-M) % min(bm, 128)
-    pk = (-K) % min(bk, 128)
-    pn = (-N) % min(bn, 128)
+    # M tiles shrink to the 8-row multiple above a short M; N and K pad to
+    # the 128-lane width of the LUT-GEMM core
+    bm = min(128, round_up(M, 8))
+    pm, pk, pn = (-M) % bm, (-K) % 128, (-N) % 128
     if pm or pk or pn:
         a = jnp.pad(a, ((0, pm), (0, pk)))
         w = jnp.pad(w, ((0, pk), (0, pn)))
-    out = lut_matmul_kernel(a, w, lut_flat, offset=offset, n_codes=n_codes,
-                            bm=bm, bk=bk, bn=bn, interpret=interpret)
+    out = lut_matmul_kernel(a, w, tab, offset=offset, n_planes=lut_planes(lut),
+                            bm=bm, interpret=interpret)
     if pk:
-        zz = lut_flat[offset * n_codes + offset].astype(jnp.int32)
-        out = out - pk * zz
+        out = out - pk * tab[offset, offset]
     return out[:M, :N]

@@ -1,46 +1,50 @@
-"""Process-wide execution-mode knob for every Pallas kernel wrapper.
+"""Where the Pallas kernels run, and where compiled programs are cached.
 
-Every kernel in this package historically hardcoded ``interpret=True`` in its
-own signature (the dev container has no TPU, so kernels run under the Pallas
-interpreter on CPU). That scattered default made the ROADMAP real-hardware
-item an N-file sweep. It now lives here, once:
+Every kernel wrapper declares ``interpret: bool | None = None`` and resolves
+it with :func:`resolve_interpret` right before ``pallas_call``. The default
+follows the backend: kernels are compiled by Mosaic on a TPU and run under
+the Pallas interpreter everywhere else (the CPU test suite). An explicit
+``True``/``False`` at a call site still wins. ``tests/test_runtime.py``
+asserts no kernel wrapper regresses to a hardcoded default.
 
-* wrappers declare ``interpret: bool | None = None`` and resolve the actual
-  value with :func:`resolve_interpret` right before ``pallas_call``;
-* the default is env-overridable — ``REPRO_INTERPRET=0`` flips the whole
-  package to compiled Mosaic kernels without touching a call site.
-
-Explicitly passing ``interpret=True/False`` at a call site still wins (tests
-pin interpret mode that way); only the *default* is centralized. The env var
-is read when a kernel is traced, so it is a process-level switch, not a
-per-call one. ``tests/test_runtime.py`` asserts no kernel wrapper regresses
-to a hardcoded default.
+:func:`enable_compile_cache` is the one place the persistent compilation
+cache is configured; the launchers and ``chip_smoke.py`` call it before
+their first compile.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 
-_ENV = "REPRO_INTERPRET"
-_FALSY = {"0", "false", "no", "off", ""}
+import jax
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: a fixed path, because the cache key includes it
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def interpret_default() -> bool:
-    """The package-wide default for ``pallas_call(interpret=...)``.
-
-    ``True`` unless ``REPRO_INTERPRET`` is set to a falsy value (``0``,
-    ``false``, ``no``, ``off``) — the one-switch flip for running on real
-    TPU hardware.
-    """
-    v = os.environ.get(_ENV)
-    if v is None:
-        return True
-    return v.strip().lower() not in _FALSY
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve_interpret(value: bool | None) -> bool:
     """Resolve a wrapper's ``interpret`` argument: an explicit ``True`` /
-    ``False`` wins; ``None`` (the signature default everywhere) defers to
-    :func:`interpret_default`."""
+    ``False`` wins; ``None`` (the signature default everywhere) compiles on
+    the TPU and interprets on any other backend."""
     if value is None:
-        return interpret_default()
+        return not on_tpu()
     return bool(value)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as JAX reads it and
+    nothing else is configured. Otherwise the cache goes to the fixed
+    ``.jax_cache`` directory of this checkout."""
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

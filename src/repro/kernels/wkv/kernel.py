@@ -40,9 +40,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref, *,
         wt = jax.lax.dynamic_slice(w, (t, 0), (1, hd))[0]
         kv = kt[:, None] * vt[None, :]                   # (hd, hd)
         out = rt @ (s + u[:, None] * kv)                 # (hd,)
-        # all-Slice index: integer dim indices break interpret-mode discharge
-        pl.store(o_ref, (slice(None), pl.dslice(t, 1), slice(None)),
-                 out[None, None, :])
+        o_ref[:, pl.ds(t, 1), :] = out[None, None, :]
         return wt[:, None] * s + kv
 
     s = jax.lax.fori_loop(0, seq, step, s0_ref[...][0])

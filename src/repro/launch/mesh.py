@@ -9,26 +9,21 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases; older ones
-    default to auto axes anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with auto (GSPMD-propagated) axes on every name."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU tests (1x1, same axis names)."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_host_multi_mesh(shape=(2, 4)):
@@ -45,4 +40,4 @@ def make_host_multi_mesh(shape=(2, 4)):
             f"host mesh {shape} needs {need} devices, found {have}; export "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} before "
             f"importing jax")
-    return compat_make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))

@@ -114,8 +114,6 @@ class CellCost:
 
 def extract(compiled, hlo_text: Optional[str] = None) -> CellCost:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per program
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = collective_bytes(text)

@@ -13,13 +13,75 @@ report plus the pool geometry. ``--block-size`` and ``--hbm-budget``
 shape the pool. ``--arrival-rate`` (arrivals per decode step,
 continuous/paged only) replays a Poisson trace instead of firing every
 request at t=0.
+
+:func:`build_engine` and :func:`serve` are the callable halves of
+:func:`main`; ``chip_smoke.py`` drives them directly.
 """
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import numpy as np
+
+
+def build_engine(arch: str = "smollm-135m", *, reduced: bool = False,
+                 approx: str | None = None, slots: int = 4,
+                 continuous: bool = False, paged: bool = False,
+                 block_size: int = 16, hbm_budget: int | None = None,
+                 max_seq: int = 256, seed: int = 0):
+    """Random-weight model (from ``seed``) behind the selected engine."""
+    from repro.configs import get_config, reduced_config
+    from repro.launch.specs import make_acfg
+    from repro.models.transformer import init_params
+    from repro.serve.engine import (ContinuousServeEngine,
+                                    PagedContinuousServeEngine, ServeEngine)
+
+    cfg = reduced_config(arch) if reduced else get_config(arch)
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    acfg = make_acfg(approx)
+    if paged:
+        return PagedContinuousServeEngine(
+            params, cfg, slots=slots, max_seq=max_seq, block_size=block_size,
+            acfg=acfg, hbm_budget=hbm_budget)
+    cls = ContinuousServeEngine if continuous else ServeEngine
+    return cls(params, cfg, slots=slots, max_seq=max_seq, acfg=acfg)
+
+
+def make_requests(vocab_size: int, n: int, new_tokens: int, seed: int = 0):
+    """``n`` random prompts of 4-11 tokens, ``new_tokens`` each."""
+    from repro.serve.engine import Request
+    rng = np.random.default_rng(seed)
+    return [Request(prompt=rng.integers(1, vocab_size, rng.integers(4, 12)
+                                        ).astype(np.int32),
+                    max_new_tokens=new_tokens)
+            for _ in range(n)]
+
+
+def serve(eng, requests, *, arrival_rate: float | None = None):
+    """Run ``requests`` through ``eng``; returns (done, seconds)."""
+    from repro.serve.engine import ServeEngine, poisson_arrivals
+    slotted = not isinstance(eng, ServeEngine)
+    if arrival_rate is not None and not slotted:
+        raise ValueError("arrival_rate needs a continuous or paged engine")
+    t0 = time.monotonic()
+    if slotted:
+        arrivals = (None if arrival_rate is None else
+                    poisson_arrivals(len(requests), arrival_rate, seed=0))
+        done = eng.run(requests, arrivals)
+    else:
+        done = eng.run(requests)
+    return done, time.monotonic() - t0
+
+
+def attn_plan_report(eng) -> dict:
+    """``describe()`` of the paged engine's attention plan."""
+    from repro.core.acu import AttnSpec, attn_plan
+    cfg, acfg = eng.cfg, eng.acfg
+    spec = AttnSpec(hq=cfg.n_heads, hkv=cfg.n_kv_heads, bk=eng.block_size,
+                    kv_layout="paged")
+    return attn_plan(acfg.acu, spec, a_bits=acfg.a_bits, mesh=False).describe()
 
 
 def main():
@@ -43,57 +105,31 @@ def main():
                     help="Poisson arrivals per decode step "
                          "(continuous/paged only)")
     args = ap.parse_args()
+    if args.arrival_rate is not None and not (args.continuous or args.paged):
+        ap.error("--arrival-rate needs --continuous or --paged")
 
-    from repro.configs import get_config, reduced_config
-    from repro.launch.specs import make_acfg
-    from repro.models.transformer import init_params
-    from repro.serve.engine import (ContinuousServeEngine,
-                                    PagedContinuousServeEngine, Request,
-                                    ServeEngine, kv_block_bytes,
-                                    poisson_arrivals)
+    from repro.kernels.runtime import enable_compile_cache
+    from repro.serve.engine import kv_block_bytes
+    enable_compile_cache()
 
-    cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    acfg = make_acfg(args.approx)
-    max_seq = 256
+    eng = build_engine(args.arch, reduced=args.reduced, approx=args.approx,
+                       slots=args.slots, continuous=args.continuous,
+                       paged=args.paged, block_size=args.block_size,
+                       hbm_budget=args.hbm_budget)
     if args.paged:
-        eng = PagedContinuousServeEngine(
-            params, cfg, slots=args.slots, max_seq=max_seq,
-            block_size=args.block_size, acfg=acfg,
-            hbm_budget=args.hbm_budget)
-        bbytes = kv_block_bytes(cfg, args.block_size)
+        bbytes = kv_block_bytes(eng.cfg, args.block_size)
         print(f"paged pool: {eng.n_blocks} blocks x {args.block_size} tok "
               f"({bbytes} B/block, budget {eng.hbm_budget} B, "
               f"{eng.n_logical} logical blocks/slot)")
-        if acfg is not None and acfg.acu is not None:
-            from repro.core.acu import AttnSpec, attn_plan
-            spec = AttnSpec(hq=cfg.n_heads, hkv=cfg.n_kv_heads,
-                            bk=args.block_size, kv_layout="paged")
-            plan = attn_plan(acfg.acu, spec, a_bits=acfg.a_bits, mesh=False)
-            for k, v in plan.describe().items():
+        if eng.acfg is not None:
+            for k, v in attn_plan_report(eng).items():
                 print(f"attn_plan.{k}: {v}")
-    else:
-        cls = ContinuousServeEngine if args.continuous else ServeEngine
-        eng = cls(params, cfg, slots=args.slots, max_seq=max_seq, acfg=acfg)
-    rng = np.random.default_rng(0)
-    reqs = [Request(prompt=rng.integers(1, cfg.vocab_size,
-                                        rng.integers(4, 12)).astype(np.int32),
-                    max_new_tokens=args.new_tokens)
-            for _ in range(args.requests)]
-    slotted = args.continuous or args.paged
-    arrivals = None
-    if args.arrival_rate is not None:
-        if not slotted:
-            ap.error("--arrival-rate needs --continuous or --paged")
-        arrivals = poisson_arrivals(len(reqs), args.arrival_rate, seed=0)
-    import time
-    t0 = time.monotonic()
-    done = eng.run(reqs, arrivals) if slotted else eng.run(reqs)
-    dt = time.monotonic() - t0
+    reqs = make_requests(eng.cfg.vocab_size, args.requests, args.new_tokens)
+    done, dt = serve(eng, reqs, arrival_rate=args.arrival_rate)
     n_tok = sum(len(r.out) for r in done)
     print(f"served {len(done)} requests, {n_tok} tokens in {dt:.2f}s "
           f"({n_tok/dt:.1f} tok/s)")
-    if slotted:
+    if args.continuous or args.paged:
         print(f"stats: {eng.stats}")
     for i, r in enumerate(done[:4]):
         print(f"req{i}: {list(r.prompt)[:6]}... -> {list(r.out)[:8]}...")

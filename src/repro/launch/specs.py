@@ -24,17 +24,24 @@ from repro.parallel import planner
 from repro.parallel.sharding import use_mesh
 
 
-def make_acfg(acu_spec):
+def make_acfg(acu_spec, *, approx_bwd: bool = False):
     """'mult:mode[:rank]' -> ApproxConfig (e.g. mul8s_1L2H:lut,
-    mul8s_trunc2:factored, mul8s_1L2H:lowrank:8)."""
+    mul8s_trunc2:factored, mul8s_1L2H:lowrank:8).
+
+    The ACU takes the Pallas kernel routes; LUT mode runs the fused
+    quantize -> LUT-GEMM -> dequant kernel, and with ``approx_bwd`` the STE
+    backward GEMMs run the fused backward kernel too."""
     if not acu_spec:
         return None
     from repro.core.acu import AcuMode, make_acu
     from repro.core.approx_ops import ApproxConfig
     parts = acu_spec.split(":")
-    name, mode = parts[0], parts[1] if len(parts) > 1 else "lut"
+    name = parts[0]
+    mode = AcuMode(parts[1] if len(parts) > 1 else "lut")
     rank = int(parts[2]) if len(parts) > 2 else 8
-    return ApproxConfig(acu=make_acu(name, AcuMode(mode), rank=rank))
+    acu = make_acu(name, mode, rank=rank, use_pallas=True,
+                   fused=mode == AcuMode.LUT)
+    return ApproxConfig(acu=acu, approx_bwd=approx_bwd)
 
 
 @dataclasses.dataclass

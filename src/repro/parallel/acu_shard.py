@@ -26,7 +26,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .planner import (GemmPartition, acu_attn_partition, acu_conv_partition,
@@ -122,12 +121,12 @@ def wrap_attn(attn_call: Callable[..., Array], ctx: MeshContext,
                 qs_b, ks_b, vs_b, info)
             return out.reshape(bl, hql, *out.shape[1:])
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(rows, cols, None, None), P(rows, cols, None, None),
                       P(rows, cols, None, None), P(None), P(None), P(None),
                       P(rows, None)),
-            out_specs=P(rows, cols, None, None), check_rep=False,
+            out_specs=P(rows, cols, None, None), check_vma=False,
         )(q, k, v, qs_a, ks_a, vs_a, rowinfo)
         return out[:b]
 
@@ -184,13 +183,13 @@ def wrap_attn_paged(attn_call: Callable[..., Array], ctx: MeshContext,
                 kp_blk, vp_blk, qs_b, ks_b, vs_b, info, pt)
             return out.reshape(bl, hql, *out.shape[1:])
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(rows, cols, None, None),
                       P(cols, None, None, None), P(cols, None, None, None),
                       P(None), P(None), P(None),
                       P(rows, None), P(rows, None)),
-            out_specs=P(rows, cols, None, None), check_rep=False,
+            out_specs=P(rows, cols, None, None), check_vma=False,
         )(q, k_pool, v_pool, qs_a, ks_a, vs_a, rowinfo, page_table)
         return out[:b]
 
@@ -219,9 +218,9 @@ def wrap_unfused(base_fn: Callable[[Array, Array], Array], ctx: MeshContext,
                 acc = jax.lax.psum(acc, part.k)
             return acc
 
-        out = shard_map(local, mesh=mesh,
-                        in_specs=(part.a_spec(), part.w_spec()),
-                        out_specs=part.out_spec(), check_rep=False)(a_p, w_p)
+        out = jax.shard_map(
+            local, mesh=mesh, in_specs=(part.a_spec(), part.w_spec()),
+            out_specs=part.out_spec(), check_vma=False)(a_p, w_p)
         if pk and m00:
             # global K shard-padding correction: applied once, after the
             # psum — each pad entry contributed m00 to exactly one k shard
@@ -270,11 +269,11 @@ def wrap_fused(fused_call: Callable[..., Array],
                 # dequant — bit-exact vs the single-device output
                 return acc.astype(jnp.float32) * (xs_b[0] * ws_blk)
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(part.a_spec(), part.w_spec(), P(None), P(None),
                       P(None, part._dim(part.cols))),
-            out_specs=part.out_spec(), check_rep=False,
+            out_specs=part.out_spec(), check_vma=False,
         )(x_p, wq_p, xs_a, xz_a, ws_p)
         return out[:M, :N]
 
@@ -326,10 +325,10 @@ def wrap_fused_bwd(bwd_call: Callable[..., Array],
                 from repro.core.quantization import pin_rounding
                 return acc.astype(jnp.float32) * pin_rounding(sa_b[0] * sb_b[0])
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(part.a_spec(), part.w_spec(), P(None), P(None)),
-            out_specs=part.out_spec(), check_rep=False,
+            out_specs=part.out_spec(), check_vma=False,
         )(a_p, b_p, sa_a, sb_a)
         return out[:M, :N]
 
@@ -408,11 +407,11 @@ def wrap_fused_grouped(grouped_call: Callable[..., Array],
                         < cnt_blk[:, :, None])
                 return jnp.where(live[..., None], deq, 0.0)
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(rows, cols, None, kdim), P(cols, kdim, None),
                       P(None), P(None), P(cols, None), P(rows, cols)),
-            out_specs=P(rows, cols, None, None), check_rep=False,
+            out_specs=P(rows, cols, None, None), check_vma=False,
         )(x4, wq, xs_a, xz_a, ws_e, cnt)
         return out.reshape(G, C, N)
 
@@ -541,11 +540,11 @@ def wrap_fused_conv(conv_call: Callable[..., Array],
                 return acc.astype(jnp.float32) * \
                     (xs_b[0] * ws_blk).reshape(1, 1, 1, -1)
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(x_rows, kdim, None, None), P(cols, kdim, None, None),
                       P(None), P(None), P(None, cols)),
-            out_specs=P(rows, None, None, cols), check_rep=False,
+            out_specs=P(rows, None, None, cols), check_vma=False,
         )(x, wq, xs_a, xz_a, ws_row)
         if band_ways > 1:
             ho, wo = spec.out_spatial
@@ -651,13 +650,13 @@ def wrap_conv_bwd_w(acc_call: Callable[..., Array], ctx: MeshContext,
 
         rm_arg = rmask if band_ways == 1 else \
             jnp.zeros((1, 1), jnp.int32)   # unused; built inside extract
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(x_rows, kdim, None, None),
                       P(g_rows, None, None, cols),
                       P(g_rows, None) if band_ways == 1 else P(None, None),
                       P(None), P(None)),
-            out_specs=P(None, kdim, cols), check_rep=False,
+            out_specs=P(None, kdim, cols), check_vma=False,
         )(xf, g, rm_arg, sx_a, sg_a)
         return out[:, :c, :cout]
 
@@ -697,10 +696,10 @@ def wrap_conv_gx_gemm(acc_call: Callable[..., Array], ctx: MeshContext,
                 acc = jax.lax.psum(acc, part.cols)
             return acc
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(None, cols), P(cols, None), P(None), P(None)),
-            out_specs=P(None, None), check_rep=False,
+            out_specs=P(None, None), check_vma=False,
         )(g2_p, b_p, sg_a, sw_a)
         if pk and m00:
             # global Cout shard-padding correction: once, after the psum
@@ -725,20 +724,22 @@ def bwd_gemms(ctx: MeshContext, part: GemmPartition
         M = g.shape[0]
         pm = (-M) % part.n_rows
         g_p = jnp.pad(g, ((0, pm), (0, 0))) if pm else g
-        out = shard_map(lambda gb, wb: gb @ wb.T, mesh=mesh,
-                        in_specs=(P(part._dim(part.rows), None), P(None, None)),
-                        out_specs=P(part._dim(part.rows), None),
-                        check_rep=False)(g_p, wf)
+        out = jax.shard_map(
+            lambda gb, wb: gb @ wb.T, mesh=mesh,
+            in_specs=(P(part._dim(part.rows), None), P(None, None)),
+            out_specs=P(part._dim(part.rows), None),
+            check_vma=False)(g_p, wf)
         return out[:M]
 
     def gw_fn(xf: Array, g: Array) -> Array:
         N = g.shape[1]
         pn = (-N) % part.n_cols
         g_p = jnp.pad(g, ((0, 0), (0, pn))) if pn else g
-        out = shard_map(lambda xb, gb: xb.T @ gb, mesh=mesh,
-                        in_specs=(P(None, None), P(None, part._dim(part.cols))),
-                        out_specs=P(None, part._dim(part.cols)),
-                        check_rep=False)(xf, g_p)
+        out = jax.shard_map(
+            lambda xb, gb: xb.T @ gb, mesh=mesh,
+            in_specs=(P(None, None), P(None, part._dim(part.cols))),
+            out_specs=P(None, part._dim(part.cols)),
+            check_vma=False)(xf, g_p)
         return out[:, :N]
 
     return gx_fn, gw_fn

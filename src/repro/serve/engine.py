@@ -355,9 +355,9 @@ class BlockAllocator:
     Block 0 is the *null* block: page tables default to it for unallocated
     logical blocks, it is never handed out and never written, so it stays
     all-zeros (non-causal/window gathers through it see exactly what a
-    contiguous cache holds past its fill). Block 1 is the *scratch* block:
-    inactive decode rows park their page table on it so their discarded
-    writes never dirty the null block. Shared prefix blocks carry one ref
+    contiguous cache holds past its fill). Block 1 is the *scratch* block
+    that released slots' page tables point at; decode never writes it,
+    since idle rows replay a live row. Shared prefix blocks carry one ref
     per sharer plus one for the prefix cache itself; a block returns to the
     free list when its refcount drains to zero.
     """
@@ -815,10 +815,16 @@ class PagedContinuousServeEngine:
             live = np.flatnonzero(active)
             if not live.size:
                 continue
+            # idle rows replay the first live row (its token, position and
+            # page table, so their KV writes repeat its own): the per-tensor
+            # activation and K/V scales of the step then depend on the live
+            # requests alone, which keeps a warm admission bitwise equal to
+            # a cold one whatever the idle rows last held
+            src = np.where(active, np.arange(slots), live[0])
             with self._mesh_scope():
                 logits, cache = self._decode(
-                    self.params, cache, jnp.asarray(cur)[:, None],
-                    jnp.asarray(pos), jnp.asarray(self._tables))
+                    self.params, cache, jnp.asarray(cur[src])[:, None],
+                    jnp.asarray(pos[src]), jnp.asarray(self._tables[src]))
             nxt = np.asarray(jnp.argmax(logits, -1))
             cur[live] = nxt[live]
             pos[live] += 1

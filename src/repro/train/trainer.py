@@ -141,7 +141,6 @@ class Trainer:
         return jax.jit(step_fn, donate_argnums=donate)
 
     def _build_dp_step(self, n_micro: int) -> Callable:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.optim.compression import EFState, compressed_psum
@@ -167,11 +166,11 @@ class Trainer:
                     one(damping_lib.tree_sqnorm(grads)),
                     one(damping_lib.tree_sqnorm(ef.residual)))
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             worker, mesh=cfg.mesh,
             in_specs=(P(), p_lead, p_batch),
             out_specs=(P(), p_lead, P(), p_lead, p_lead),
-            check_rep=False)
+            check_vma=False)
 
         def step_fn(params, opt_state, resid, batch):
             mean, new_resid, loss, local_sq, resid_sq = sharded(

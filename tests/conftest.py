@@ -1,16 +1,10 @@
 import numpy as np
 import pytest
+from hypothesis import settings
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, settings
-
-# fast hypothesis profile: CI-sized example counts (the offline fallback shim
-# honors the same profile API — see _hypothesis_compat.py)
+# fast hypothesis profile: CI-sized example counts
 settings.register_profile("repro", max_examples=25, deadline=None)
 settings.load_profile("repro")
-
-
-def pytest_report_header(config):
-    return f"hypothesis: {'real' if HAVE_HYPOTHESIS else 'offline fallback shim'}"
 
 
 @pytest.fixture

@@ -63,8 +63,8 @@ def test_restore_with_shardings(tmp_path):
     """Elastic restart: restore onto explicit (single-device) shardings."""
     tree = make_tree(jax.random.PRNGKey(2))
     C.save(str(tmp_path), 1, tree)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     restored, _ = C.restore(str(tmp_path), 1, tree, shardings=sh)

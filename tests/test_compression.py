@@ -21,16 +21,15 @@ def test_psum_path_roundtrips_through_compress(rng):
     standalone compress() — with the pmax'd amax passed in, its transmitted
     value is exactly decompress(compress(g, amax)) and the standalone
     round-trip bound holds inside the collective path too."""
-    from repro.launch.mesh import compat_make_mesh
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
-    mesh = compat_make_mesh((1,), ("dp",))
+    mesh = make_mesh((1,), ("dp",))
 
     g = jnp.asarray(rng.normal(size=(64,)) * 3, jnp.float32)
     ef = init_ef({"w": g})
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(), P()),
-                       out_specs=(P(), P()), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     def step(g, r):
         out, ef2 = compressed_psum({"w": g}, EFState(residual={"w": r}), "dp")
         return out["w"], ef2.residual["w"]
@@ -61,9 +60,8 @@ def test_compress_external_amax_roundtrip_bound(rng):
 
 def test_error_feedback_unbiased_over_steps(rng):
     """Sum of transmitted values + residual == sum of true gradients."""
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("dp",))
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("dp",))
     from jax.sharding import PartitionSpec as P
 
     grads = {"w": jnp.asarray(rng.normal(size=(32,)), jnp.float32)}
@@ -71,8 +69,8 @@ def test_error_feedback_unbiased_over_steps(rng):
     sent_total = jnp.zeros(32)
     true_total = jnp.zeros(32)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(), P()),
-                       out_specs=(P(), P()), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     def step(g, r):
         out, ef2 = compressed_psum({"w": g}, EFState(residual={"w": r}), "dp")
         return out["w"], ef2.residual["w"]

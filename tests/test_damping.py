@@ -254,7 +254,6 @@ def test_compressed_psum_stats_pair(mesh):
     """with_stats exports the free estimator pair: mean per-worker |g|^2,
     |mean|^2, residual energy — and the noisier the shards, the wider the
     small/large gap."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.compression import EFState, compressed_psum
@@ -271,13 +270,13 @@ def test_compressed_psum_stats_pair(mesh):
                 jax.tree.map(lambda x: x[None], ef.residual),
                 jax.tree.map(lambda x: jnp.reshape(x, (1,)), stats))
 
-    f = shard_map(worker, mesh=mesh,
-                  in_specs=(P("data"), P("data")),
-                  out_specs=(P("data"), P("data"),
-                             jax.tree.map(lambda _: P("data"), {
-                                 "gsq_small": 0, "gsq_big": 0,
-                                 "resid_sq": 0})),
-                  check_rep=False)
+    f = jax.shard_map(worker, mesh=mesh,
+                      in_specs=(P("data"), P("data")),
+                      out_specs=(P("data"), P("data"),
+                                 jax.tree.map(lambda _: P("data"), {
+                                     "gsq_small": 0, "gsq_big": 0,
+                                     "resid_sq": 0})),
+                      check_vma=False)
     summed, resid, stats = f(jnp.asarray(g), jnp.zeros_like(jnp.asarray(g)))
     mean = np.asarray(summed["g"])[0]
     small = float(np.asarray(stats["gsq_small"])[0])

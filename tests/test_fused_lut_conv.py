@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import assume, given, settings, strategies as st
+from hypothesis import assume, given, settings, strategies as st
 from repro.core import build_lut, get_multiplier, make_acu
 from repro.core.acu import (AcuMode, ConvSpec, conv_plan,
                             resolve_conv_padding)
@@ -428,7 +428,6 @@ def test_spatial_tiling_pick_respects_budget():
 
 # ---------------------------------------------------------------------------
 # property-based tiling harness: hypothesis strategy over ConvSpec geometry
-# (offline via tests/_hypothesis_compat.py)
 # ---------------------------------------------------------------------------
 
 _BIASED_MULT = dataclasses.replace(

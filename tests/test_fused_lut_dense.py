@@ -198,7 +198,7 @@ def test_acu_matmul_unchanged_by_fused_flag():
 
 import dataclasses
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.core.multipliers import make_exact
 from repro.kernels.fused_lut_dense.ops import fused_lut_bwd
 from repro.kernels.fused_lut_dense.ref import fused_lut_bwd_ref

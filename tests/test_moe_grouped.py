@@ -496,9 +496,9 @@ def test_build_step_meta_surfaces_moe_dispatch():
     """build_step records the resolved dispatch geometry for MoE configs so
     the dry-run can report it per cell (non-MoE configs get no entry)."""
     from repro.configs.shapes import ShapeSpec
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import build_step
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shape = ShapeSpec("tiny", 8, 2, "train")
     bundle = build_step(CFG_MOE, shape, mesh)
     geo = bundle.meta["moe_dispatch"]

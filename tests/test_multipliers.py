@@ -2,7 +2,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, strategies as st
+from hypothesis import given, strategies as st
 
 from repro.core.multipliers import (REGISTRY, error_stats, get_multiplier,
                                     make_bam, make_drum, make_exact,

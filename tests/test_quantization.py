@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, strategies as st
+from hypothesis import given, strategies as st
 
 from repro.core.quantization import (QParams, acu_operand, affine_qparams,
                                      dequantize, fake_quantize, quantize,
