@@ -39,6 +39,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 ARCH = "smollm-135m"
 APPROX = "mul8s_1L2H:lut"
+REQUESTS, NEW_TOKENS = 8, 16              # serve phase
+STEPS, BATCH, SEQ = 3, 8, 256             # train phase
+MESH_LAYERS, MESH_SEQ = 2, 128            # data-parallel step on (2, 2)
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
                   "/jax/core/compile/backend_compile_duration")
@@ -184,23 +187,21 @@ def plans_ok(plans, want_attn: str | None, want_bwd: bool) -> bool:
     return ok and (want_attn is None or seen_attn) and (seen_bwd or not want_bwd)
 
 
-def serve_phase(log: CompileLog, seed: int, *, reduced: bool = False,
-                requests: int = 8, new_tokens: int = 16) -> bool:
+def serve_phase(log: CompileLog, seed: int) -> bool:
     from repro.launch.serve import (attn_plan_report, build_engine,
                                     make_requests, serve)
     from repro.core.approx_ops import resolved_plans
     n0 = len(resolved_plans())
     with phase("serve", log):
-        eng = build_engine(ARCH, reduced=reduced, approx=APPROX, paged=True,
-                           seed=seed)
-        reqs = make_requests(eng.cfg.vocab_size, requests, new_tokens, seed)
+        eng = build_engine(ARCH, approx=APPROX, paged=True, seed=seed)
+        reqs = make_requests(eng.cfg.vocab_size, REQUESTS, NEW_TOKENS, seed)
         done, secs = serve(eng, reqs)
     print(f"attn_plan {json.dumps(attn_plan_report(eng), default=str)}")
     vocab = eng.cfg.vocab_size
-    ok = len(done) == requests
+    ok = len(done) == REQUESTS
     for i, r in enumerate(done):
         out = [int(t) for t in r.out]
-        ok &= len(out) == new_tokens and all(0 <= t < vocab for t in out)
+        ok &= len(out) == NEW_TOKENS and all(0 <= t < vocab for t in out)
         print(f"request {i}: prompt {len(r.prompt)} tokens -> {len(out)} "
               f"tokens {out}")
     print(f"served {sum(len(r.out) for r in done)} tokens in {secs:.3f}s; "
@@ -209,27 +210,24 @@ def serve_phase(log: CompileLog, seed: int, *, reduced: bool = False,
                     want_bwd=False) and ok
 
 
-def train_phase(log: CompileLog, seed: int, *, reduced: bool = False,
-                steps: int = 3, batch: int = 8, seq: int = 256) -> bool:
+def train_phase(log: CompileLog, seed: int) -> bool:
     import math
 
     from repro.core.approx_ops import resolved_plans
     from repro.launch.train import train
     n0 = len(resolved_plans())
     with phase("train", log):
-        trainer = train(ARCH, steps=steps, batch=batch, seq=seq,
-                        approx=APPROX, reduced=reduced, log_every=1,
-                        seed=seed)
+        trainer = train(ARCH, steps=STEPS, batch=BATCH, seq=SEQ,
+                        approx=APPROX, log_every=1, seed=seed)
     losses = [h["loss"] for h in trainer.history if "loss" in h]
     for h in trainer.history:
         print(f"train {h}")
-    ok = len(losses) == steps and all(math.isfinite(l) for l in losses)
+    ok = len(losses) == STEPS and all(math.isfinite(l) for l in losses)
     print(f"losses {losses}")
     return plans_ok(resolved_plans()[n0:], None, want_bwd=True) and ok
 
 
-def mesh_phase(log: CompileLog, seed: int, *, reduced: bool = False,
-               n_layers: int = 2, seq: int = 128) -> bool:
+def mesh_phase(log: CompileLog, seed: int) -> bool:
     """Sharded approx_dense and one data-parallel QAT step on (2, 2),
     each against its single-device counterpart."""
     import dataclasses
@@ -238,7 +236,7 @@ def mesh_phase(log: CompileLog, seed: int, *, reduced: bool = False,
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.configs import get_config, reduced_config
+    from repro.configs import get_config
     from repro.core.approx_ops import approx_dense
     from repro.launch.mesh import make_mesh
     from repro.launch.specs import make_acfg
@@ -249,7 +247,7 @@ def mesh_phase(log: CompileLog, seed: int, *, reduced: bool = False,
     from repro.train.trainer import Trainer, TrainerConfig
 
     mesh = make_mesh((2, 2), ("data", "model"))
-    cfg = reduced_config(ARCH) if reduced else get_config(ARCH)
+    cfg = get_config(ARCH)
     rng = np.random.default_rng(seed)
     ok = True
 
@@ -267,10 +265,11 @@ def mesh_phase(log: CompileLog, seed: int, *, reduced: bool = False,
                       f" vs one chip", sharded, one)
 
     with phase("mesh dp QAT step", log):
-        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+        cfg = dataclasses.replace(cfg, n_layers=MESH_LAYERS)
         acfg = make_acfg(APPROX, approx_bwd=True)
         params = init_params(jax.random.PRNGKey(seed), cfg)
-        toks = rng.integers(0, cfg.vocab_size, (8, seq + 1)).astype(np.int32)
+        toks = rng.integers(0, cfg.vocab_size,
+                            (BATCH, MESH_SEQ + 1)).astype(np.int32)
         batch = {"tokens": jnp.asarray(toks[:, :-1]),
                  "labels": jnp.asarray(toks[:, 1:])}
 
@@ -286,7 +285,7 @@ def mesh_phase(log: CompileLog, seed: int, *, reduced: bool = False,
         # int32 code sum x scale / W, the same AdamW update
         n_dp = mesh.shape["data"]
         grad_fn = jax.jit(jax.value_and_grad(loss))
-        rows = 8 // n_dp
+        rows = BATCH // n_dp
         per = [grad_fn(params, {k: v[i * rows:(i + 1) * rows]
                                 for k, v in batch.items()})[1]
                for i in range(n_dp)]

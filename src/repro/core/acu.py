@@ -34,7 +34,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import functools
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -211,9 +211,10 @@ class MatmulPlan:
     kernel: ``plan(x, wq, x_scale, x_zp, w_scale) -> float32`` where ``x`` is
     the float activation matrix and ``wq`` the shifted weight codes.
 
-    ``partition`` records the mesh partition the plan executes under
-    (``None`` = single-device); the wrapped ``fn`` already contains the
-    ``shard_map`` — callers never change.
+    ``route`` names the callable ``fn`` runs, recorded where it was
+    chosen. ``partition`` records the mesh partition the plan executes
+    under (``None`` = single-device); the wrapped ``fn`` already contains
+    the ``shard_map`` — callers never change.
     """
 
     mode: AcuMode
@@ -221,20 +222,11 @@ class MatmulPlan:
     use_pallas: bool
     fused: bool
     fn: Callable[..., Array]
+    route: str
     partition: Optional[object] = None   # parallel.planner.GemmPartition
 
     def __call__(self, *args) -> Array:
         return self.fn(*args)
-
-    @property
-    def route(self) -> str:
-        if self.fused:
-            return "fused_lut_dense"
-        if self.mode in (AcuMode.EXACT, AcuMode.FACTORED):
-            return f"{self.mode.value}_int_matmul"
-        if self.use_pallas and self.mode in (AcuMode.LUT, AcuMode.LOWRANK):
-            return "lut_matmul" if self.mode == AcuMode.LUT else "err_matmul"
-        return f"{self.mode.value}_jnp_oracle"
 
     def describe(self) -> dict:
         part = self.partition
@@ -252,46 +244,48 @@ def _no_oracle_on_tpu(what: str, report) -> None:
         raise RuntimeError(f"{what} has no kernel route on the TPU: {report}")
 
 
-def _resolve_unfused(acu: Acu) -> Callable[[Array, Array], Array]:
+def _resolve_unfused(acu: Acu) -> tuple[str, Callable[[Array, Array], Array]]:
     """The unfused integer-operand GEMM for ``acu`` (native int matmuls, the
-    per-mode Pallas kernels, or — off the TPU only — the jnp oracles)."""
+    per-mode Pallas kernels, or — off the TPU only — the jnp oracles), with
+    the name of its route."""
     if acu.mode == AcuMode.EXACT:
         def fn(a, w):
             if acu.bits <= 8:
                 return jax.lax.dot(a.astype(jnp.int8), w.astype(jnp.int8),
                                    preferred_element_type=jnp.int32)
             return a.astype(jnp.int32) @ w.astype(jnp.int32)
-        return fn
+        return "exact_int_matmul", fn
     if acu.mode == AcuMode.FACTORED:
         def fn(a, w):
             return (a & acu.mask).astype(jnp.int32) @ \
                    (w & acu.mask).astype(jnp.int32)
-        return fn
+        return "factored_int_matmul", fn
     if acu.mode == AcuMode.LUT:
         if acu.use_pallas:
             from repro.kernels.lut_matmul import ops as lops
-            return lambda a, w: lops.lut_matmul(
+            return "lut_matmul", lambda a, w: lops.lut_matmul(
                 a, w, acu.lut, acu.offset, interpret=acu.interpret)
         _no_oracle_on_tpu("LUT GEMM", "use_pallas=False")
         if acu.lut_chunk == 0:
             # paper's "baseline approximate": LUTs without the
             # vectorization/chunking optimizations — one (M, K, N) gather
             from repro.kernels.lut_matmul.ref import lut_matmul_ref
-            return lambda a, w: lut_matmul_ref(
+            return "lut_jnp_oracle", lambda a, w: lut_matmul_ref(
                 a, w, jnp.asarray(acu.lut).reshape(-1), acu.offset,
                 acu.multiplier.n_codes)
-        return lambda a, w: acu._lut_matmul_jnp(a, w, k_chunk=acu.lut_chunk)
+        return "lut_jnp_oracle", lambda a, w: acu._lut_matmul_jnp(
+            a, w, k_chunk=acu.lut_chunk)
     if acu.mode == AcuMode.LOWRANK:
         if acu.use_pallas:
             from repro.kernels.err_matmul import ops as eops
-            return lambda a, w: eops.err_matmul(
+            return "err_matmul", lambda a, w: eops.err_matmul(
                 a, w, jnp.asarray(acu.lowrank.f), jnp.asarray(acu.lowrank.g),
                 acu.offset, interpret=acu.interpret)
         _no_oracle_on_tpu("LOWRANK GEMM", "use_pallas=False")
-        return acu._lowrank_matmul_jnp
+        return "lowrank_jnp_oracle", acu._lowrank_matmul_jnp
     # FUNCTIONAL: stream over K chunks to bound the (M, Kc, N) intermediate
     _no_oracle_on_tpu("FUNCTIONAL GEMM", "jnp only")
-    return acu._functional_matmul_jnp
+    return "functional_jnp_oracle", acu._functional_matmul_jnp
 
 
 def _resolve_mesh(mesh):
@@ -356,22 +350,31 @@ def matmul_plan(acu: Acu, *, a_bits: Optional[int] = None,
                 lambda *args: fused_call(*args, emit_acc=True),
                 ctx, partition, acu.m00())
         return MatmulPlan(mode=acu.mode, bits=acu.bits, use_pallas=True,
-                          fused=True, fn=fn, partition=partition)
+                          fused=True, fn=fn, route="fused_lut_dense",
+                          partition=partition)
 
-    fn = _resolve_unfused(acu)
+    route, fn = _resolve_unfused(acu)
     if partition is not None:
         fn = acu_shard.wrap_unfused(fn, ctx, partition, acu.m00())
     return MatmulPlan(mode=acu.mode, bits=acu.bits, use_pallas=acu.use_pallas,
-                      fused=False, fn=fn, partition=partition)
+                      fused=False, fn=fn, route=route, partition=partition)
+
+
+class MatmulBwdPlan(NamedTuple):
+    """The approximate STE backward GEMM pair and the route both run."""
+
+    gx: Callable[..., Array]
+    gw: Callable[..., Array]
+    route: str
 
 
 def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
                     fused: Optional[bool] = None, mesh=None
-                    ) -> tuple[Callable[..., Array], Callable[..., Array]]:
+                    ) -> MatmulBwdPlan:
     """Resolve the *approximate* STE backward GEMM pair for one ACU.
 
-    Returns ``(gx_fn, gw_fn)``; each is ``fn(a, b, sa, sb) -> f32 (M, N)``
-    computing the approximate GEMM of two **float** operands quantized
+    Returns ``(gx_fn, gw_fn, route)``; each fn is
+    ``fn(a, b, sa, sb) -> f32 (M, N)`` computing the approximate GEMM of two **float** operands quantized
     per-tensor symmetric (zero-point 0 — gradients are zero-centred) with a
     single combined-scale dequant ``acc * (sa * sb)``. The caller computes
     ``sa``/``sb`` on the full tensors (``symmetric_qparams(amax, a_bits)``)
@@ -420,14 +423,14 @@ def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
                 bwd_call, lambda *args: bwd_call(*args, emit_acc=True),
                 ctx, part, acu.m00())
 
-        return route(gx_part), route(gw_part)
+        return MatmulBwdPlan(route(gx_part), route(gw_part), "fused_lut_bwd")
 
     # unfused: quantize outside (full tensors, global scales), run the
     # mode's integer GEMM — sharded via the permuted partition when a mesh
     # is active — dequant once. Bit-identical to the fused kernel for LUT
     # mode (same quantizer expression, same int32 sums, same combined-scale
     # rounding), so this composition doubles as the bit-exactness oracle.
-    base = _resolve_unfused(acu)
+    base_route, base = _resolve_unfused(acu)
     lo = -(1 << (a_bits - 1))
     hi = (1 << (a_bits - 1)) - 1
 
@@ -450,7 +453,8 @@ def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
 
         return fn
 
-    return route(gx_part), route(gw_part)
+    return MatmulBwdPlan(route(gx_part), route(gw_part),
+                         f"quantized_{base_route}")
 
 
 # ---------------------------------------------------------------------------

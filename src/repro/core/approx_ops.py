@@ -121,21 +121,18 @@ def _get_ste_fn(acu: Acu, a_bits: int, w_bits: int, fused: bool = False,
         return _STE_CACHE[key]
 
     plan = matmul_plan(acu, a_bits=a_bits, fused=fused, mesh=ctx or False)
-    # matmul_bwd_plan serves the fused backward kernel exactly when the
-    # forward plan is fused
-    bwd_route = ("exact_f32" if not approx_bwd else
-                 "fused_lut_bwd" if plan.fused else "quantized_unfused")
-    _RESOLVED[key] = {"op": "gemm", **plan.describe(), "bwd_route": bwd_route}
+    bwd_route = "exact_f32"
     if approx_bwd:
         from .acu import matmul_bwd_plan
-        gx_bwd, gw_bwd = matmul_bwd_plan(acu, a_bits=a_bits, fused=fused,
-                                         mesh=ctx or False)
+        gx_bwd, gw_bwd, bwd_route = matmul_bwd_plan(
+            acu, a_bits=a_bits, fused=fused, mesh=ctx or False)
     elif plan.partition is not None:
         from repro.parallel.acu_shard import bwd_gemms
         gx_gemm, gw_gemm = bwd_gemms(ctx, plan.partition)
     else:
         gx_gemm = lambda g, wf: g @ wf.T
         gw_gemm = lambda xf, g: xf.T @ g
+    _RESOLVED[key] = {"op": "gemm", **plan.describe(), "bwd_route": bwd_route}
 
     @jax.custom_vjp
     def ste_matmul(x, w, xs, xz, ws, wz):
@@ -553,8 +550,8 @@ def _conv_bwd_fns(acu: Acu, plan, a_bits: int, ctx):
     banded = (plan.bwd_route == "banded" and acu.mode == AcuMode.LUT
               and acu.use_pallas and acu.lut is not None)
     if not banded:
-        gx_d, gw_d = matmul_bwd_plan(acu, a_bits=a_bits, fused=plan.fused,
-                                     mesh=ctx or False)
+        gx_d, gw_d, _ = matmul_bwd_plan(acu, a_bits=a_bits,
+                                        fused=plan.fused, mesh=ctx or False)
 
         def gx_fn(g, wf, sg, sw):
             g2 = g.reshape(-1, cout).astype(jnp.float32)

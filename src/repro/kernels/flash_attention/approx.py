@@ -12,8 +12,8 @@ into the streaming-softmax loop. Per (batch*head, q_block) grid step:
 * the probabilities are quantized to static-scale codes ``round(p * hi)``
   (p is in [0, 1] post-softmax, so the scale needs no amax) and PV is a
   second int32 LUT-gather GEMM over the key block, Sk-pad corrected in int
-  space, dequantized with the pre-pinned ``pin(sv / hi)`` scale into the
-  float accumulator rescale.
+  space, added unscaled into the float accumulator rescale; the pre-pinned
+  ``pin(sv / hi)`` dequant scale is applied once, with the final 1/l.
 
 Emulation semantics (what "approximate attention on the ACU" means here):
 
@@ -21,14 +21,18 @@ Emulation semantics (what "approximate attention on the ACU" means here):
   the key-block multiple) is corrected in integer space, so
   the result is independent of the tile geometry — exactly like the dense
   and conv kernels;
-* *masked keys that exist in the input* (left-pad slots below ``kv_start``,
-  cache positions at/above ``kv_len``, causally-future or out-of-window
-  keys) get probability 0.0, which quantizes to code 0 — and the ACU still
-  multiplies code 0 by the key's V codes, contributing ``LUT[0, v]`` per
-  masked key. That is the faithful hardware emulation (a real ACU array
-  multiplies everything in the tile); for every registered multiplier
-  ``M[0, x] == 0`` so the contribution vanishes, and for biased synthetic
-  multipliers the oracle reproduces it bit-for-bit;
+* *masked keys inside the row's written extent* ``[kv_start, kv_len)``
+  (causally-future or out-of-window keys) get probability 0.0, which
+  quantizes to code 0 — and the ACU still multiplies code 0 by the key's V
+  codes, contributing ``LUT[0, v]`` per masked key. That is the faithful
+  hardware emulation (a real ACU array multiplies everything in the tile);
+  for every registered multiplier ``M[0, x] == 0`` so the contribution
+  vanishes, and for biased synthetic multipliers the oracle reproduces it
+  bit-for-bit;
+* *positions outside the written extent* (left-pad slots below
+  ``kv_start``, cache positions at/above ``kv_len``) hold no key: their V
+  enters the PV GEMM as the zero code, contributing ``M[0, 0]`` each, so
+  whatever a reused cache block still holds there is never observable;
 * the causal block-skip bound (blocks no query in the tile can see are never
   executed) is part of the defined semantics, and the oracle replicates it.
 
@@ -43,8 +47,13 @@ cannot re-fuse. On the TPU the kernel is compiled by Mosaic and the oracle
 by XLA; their elementwise float ops round identically, but a reduction's
 association order is each compiler's own, so the one float reduction (the
 softmax row sum) is an explicit pairwise tree of adds (:func:`_row_sum`).
-Both LUT GEMMs are exact integer arithmetic on the shared core
-(:mod:`repro.kernels.lut_gather`). Scales are pinned with ``pin_rounding``
+Both LUT GEMMs are exact integer arithmetic, passed to the block as
+``gemm``: the kernel runs the shared core (:mod:`repro.kernels.lut_gather`),
+the oracle a plain table gather, and the integer results are equal. The two
+GEMMs compile differently, so no float multiply of a GEMM result may feed
+an add directly (XLA CPU might contract the pair into an FMA in one program
+and not the other): that is why the PV accumulator sums raw int32 products and the
+PV scale waits for the end. Scales are pinned with ``pin_rounding``
 OUTSIDE the kernel and passed in as SMEM scalars, so single-device and
 sharded runs also see identical bits.
 
@@ -64,9 +73,9 @@ change inside the loop is where logical block ``ki`` starts —
 callback ``_online_block`` takes. The paged oracle
 (:func:`~.ref.approx_attention_paged_ref`) drives the same body with the
 same page table, so paged == contiguous == oracle bitwise whenever the
-gathered blocks hold the same values as the contiguous layout (masked keys
-keep the faithful ``LUT[0, ·]`` contribution either way, so under a biased
-multiplier a recycled block's stale codes past ``kv_len`` are observable).
+gathered blocks hold the same values as the contiguous layout inside each
+row's written extent; a recycled block's stale tail past ``kv_len`` is
+never observable.
 """
 from __future__ import annotations
 
@@ -117,10 +126,10 @@ def attn_scales(q_scale, k_scale, v_scale, d_real: int, hi: int):
     return score, pv
 
 
-def _online_block(ki, carry, *, qq, q_pos, load_kv, lut, m00, sks, svs,
+def _online_block(ki, carry, *, qq, q_pos, load_kv, gemm, m00, sks, svs,
                   score_scale, pv_scale, kv_start, kv_len, bq: int, bk: int,
-                  seq_k_real: int, d_real: int, n_planes: int, offset: int,
-                  lo: int, hi: int, causal: bool, window: int | None,
+                  seq_k_real: int, d_real: int, offset: int, lo: int,
+                  hi: int, causal: bool, window: int | None,
                   softcap: float | None):
     """One KV block of the approximate online softmax — the shared core.
 
@@ -134,17 +143,21 @@ def _online_block(ki, carry, *, qq, q_pos, load_kv, lut, m00, sks, svs,
     table for the paged layout), the oracle slices whole arrays. Masking,
     positions and pad corrections always speak logical coordinates, so the
     two layouts agree bit for bit when the gathered blocks hold the same
-    values. Both int32 GEMMs run on the shared LUT-GEMM core
-    (:func:`~repro.kernels.lut_gather.lut_gemm`) over the padded table
-    ``lut``.
+    values. ``gemm(a, b)`` is the int32 LUT GEMM ``sum_k LUT[a[m, k], b[k,
+    n]]`` over index-space codes: the kernel passes the shared core
+    (:func:`~repro.kernels.lut_gather.lut_gemm`), the oracle a plain
+    gather.
     """
     m, l, acc = carry
     kf, vf = load_kv(ki)
     dp = kf.shape[-1]
     kq = _quantize_sym(kf, sks, lo, hi, offset)
-    vq = _quantize_sym(vf, svs, lo, hi, offset)
+    # outside [kv_start, kv_len) there is no key: V enters as the zero code
+    kv_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, dp), 0)
+    vq = jnp.where((kv_pos >= kv_start) & (kv_pos < kv_len),
+                   _quantize_sym(vf, svs, lo, hi, offset), offset)
 
-    s_int = lut_gemm(qq, kq.T, lut, n_planes=n_planes)         # (bq, bk)
+    s_int = gemm(qq, kq.T)                                     # (bq, bk)
     s_int = s_int - (dp - d_real) * m00
     s = s_int.astype(jnp.float32) * score_scale
     if softcap is not None:
@@ -163,10 +176,9 @@ def _online_block(ki, carry, *, qq, q_pos, load_kv, lut, m00, sks, svs,
     # contraction runs on the ACU
     l_new = alpha * l + _row_sum(p)
     pq = jnp.clip(jnp.round(p * hi), 0, hi).astype(jnp.int32) + offset
-    pv_int = lut_gemm(pq, vq, lut, n_planes=n_planes)          # (bq, dp)
+    pv_int = gemm(pq, vq)                                      # (bq, dp)
     pv_int = pv_int - jnp.clip((ki + 1) * bk - seq_k_real, 0, bk) * m00
-    pv = pv_int.astype(jnp.float32) * pv_scale
-    acc_new = acc * alpha[:, None] + pv
+    acc_new = acc * alpha[:, None] + pv_int.astype(jnp.float32)
     return m_new, l_new, acc_new
 
 
@@ -201,17 +213,17 @@ def _attend(q_ref, lut_ref, info_ref, sq_ref, sk_ref, sv_ref, ss_ref,
         n_kv_eff = n_kv
 
     body = functools.partial(
-        _online_block, qq=qq, q_pos=q_pos, load_kv=load_kv, lut=lut_ref,
+        _online_block, qq=qq, q_pos=q_pos, load_kv=load_kv,
+        gemm=functools.partial(lut_gemm, lut=lut_ref, n_planes=n_planes),
         m00=m00_ref[0], sks=sk_ref[0], svs=sv_ref[0], score_scale=ss_ref[0],
         pv_scale=pvs_ref[0], kv_start=kv_start, kv_len=kv_len, bq=bq, bk=bk,
-        seq_k_real=seq_k_real, d_real=d_real, n_planes=n_planes,
-        offset=offset, lo=lo, hi=hi, causal=causal, window=window,
-        softcap=softcap)
+        seq_k_real=seq_k_real, d_real=d_real, offset=offset, lo=lo, hi=hi,
+        causal=causal, window=window, softcap=softcap)
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     acc0 = jnp.zeros((bq, dp), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_kv_eff, body, (m0, l0, acc0))
-    out = acc / jnp.maximum(l, 1e-30)[:, None]
+    out = acc * pvs_ref[0] / jnp.maximum(l, 1e-30)[:, None]
     o_ref[...] = out[None]
 
 
@@ -464,9 +476,8 @@ def approx_flash_attention_paged(q, k_pool, v_pool, lut, offset, q_scale,
     the physical KV block pool shared by every sequence (``P`` physical
     blocks of ``bk`` positions each, per KV head); ``page_table``:
     (B*Hq, n_logical) int32, each row mapping its logical KV blocks to
-    physical block indices (entries past the row's allocation should point
-    at an always-zero block so non-causal masks still see the contiguous
-    layout's zeros); ``rowinfo``: (B*Hq, 3) int32 logical
+    physical block indices (entries past the row's allocation may point at
+    any pool block: nothing past ``kv_len`` is observable); ``rowinfo``: (B*Hq, 3) int32 logical
     ``[q_base, kv_start, kv_len]`` — REQUIRED here, there is no full-pool
     default that makes sense. ``rep = Hq // Hkv`` maps query row
     ``b`` to pool row ``(b // rep) % Hkv``.

@@ -10,7 +10,11 @@ inside the same ``fori_loop`` shape, under jit — identical loop-body jaxprs
 compile to identical machine code, which is the bitwise contract. What the
 oracle independently exercises is the *orchestration*: python loops over
 (row, q-block) instead of a Pallas grid, whole-array indexing instead of
-BlockSpec pipelines, and the GQA ``b // rep`` mapping as plain indexing.
+BlockSpec pipelines, and the GQA ``b // rep`` mapping as plain indexing —
+and the *integer GEMMs*: the block takes its LUT GEMM as an argument, and
+the oracle passes a plain table gather (:func:`_take_gemm`) where the
+kernel passes the lane-gather/one-hot core. Integer results are exact, so
+the shared float body still pins the bits.
 """
 from __future__ import annotations
 
@@ -43,13 +47,26 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _take_gemm(tab):
+    """``gemm(a, b)[m, n] = sum_k tab[a[m, k], b[k, n]]`` in int32 by one
+    ``jnp.take`` of the flattened (R, L) table — the oracle's LUT GEMM."""
+    flat = tab.reshape(-1)
+    width = tab.shape[1]
+
+    def gemm(a, b):
+        idx = a[:, :, None] * width + b[None, :, :]
+        return jnp.take(flat, idx).sum(axis=1, dtype=jnp.int32)
+    return gemm
+
+
 def _attend_rows(qp, lut, info, sqs, sks, svs, score_scale, pv_scale, m00,
                  kv_of_row, *, n_kv: int, causal: bool, window: int | None,
                  softcap: float | None, seq_k_real: int, d_real: int,
                  n_planes: int, offset: int, lo: int, hi: int, bq: int,
                  bk: int):
     """Python (row, q-block) orchestration of ``_online_block``;
-    ``kv_of_row(b)`` -> the row's ``load_kv(ki)``."""
+    ``kv_of_row(b)`` -> the row's ``load_kv(ki)``. ``n_planes`` is the
+    kernel's digit-plane count; the gather needs none."""
     from .approx import NEG_INF, _online_block, _quantize_sym, \
         causal_block_bound
 
@@ -69,17 +86,16 @@ def _attend_rows(qp, lut, info, sqs, sks, svs, score_scale, pv_scale, m00,
                 n_kv_eff = n_kv
             body = functools.partial(
                 _online_block, qq=qq, q_pos=q_pos, load_kv=kv_of_row(b),
-                lut=lut, m00=m00[0], sks=sks[0], svs=svs[0],
+                gemm=_take_gemm(lut), m00=m00[0], sks=sks[0], svs=svs[0],
                 score_scale=score_scale[0], pv_scale=pv_scale[0],
                 kv_start=kv_start, kv_len=kv_len, bq=bq, bk=bk,
-                seq_k_real=seq_k_real, d_real=d_real, n_planes=n_planes,
-                offset=offset, lo=lo, hi=hi, causal=causal, window=window,
-                softcap=softcap)
+                seq_k_real=seq_k_real, d_real=d_real, offset=offset, lo=lo,
+                hi=hi, causal=causal, window=window, softcap=softcap)
             m0 = jnp.full((bq,), NEG_INF, jnp.float32)
             l0 = jnp.zeros((bq,), jnp.float32)
             acc0 = jnp.zeros((bq, dp), jnp.float32)
             m, l, acc = jax.lax.fori_loop(0, n_kv_eff, body, (m0, l0, acc0))
-            q_blocks.append(acc / jnp.maximum(l, 1e-30)[:, None])
+            q_blocks.append(acc * pv_scale[0] / jnp.maximum(l, 1e-30)[:, None])
         out_rows.append(jnp.concatenate(q_blocks, axis=0))
     return jnp.stack(out_rows)
 

@@ -61,16 +61,19 @@ def pad_lut(lut) -> tuple[jnp.ndarray, int]:
 def lut_planes(lut) -> int:
     """How many signed base-256 digits the table's entries need (1-4).
 
-    Read from the table's values when they are concrete; a traced table
-    gets all four, which covers every int32."""
+    ``p`` digits in [-128, 127] reach exactly ``[-128 s, 127 s]`` with
+    ``s = (256**p - 1) / 255``. Four cover every int32: a top digit that
+    leaves int8 wraps by ``2**32``, which the int32 sum drops anyway. Read
+    from the table's values when they are concrete; a traced table gets
+    all four."""
     try:
         tab = np.asarray(lut, np.int64)
     except jax.errors.TracerArrayConversionError:
         return 4
     lo, hi = int(tab.min()), int(tab.max())
     for p in (1, 2, 3):
-        bound = 128 * 256 ** (p - 1)
-        if -bound <= lo and hi < bound:
+        span = (256 ** p - 1) // 255
+        if -128 * span <= lo and hi <= 127 * span:
             return p
     return 4
 

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.configs import reduced_config
-from repro.models.transformer import apply_model, init_cache, init_params
+from repro.models.transformer import (apply_model, init_cache,
+                                      init_paged_cache, init_params)
 from repro.serve.engine import (ContinuousServeEngine,
                                 PagedContinuousServeEngine, Request,
                                 ServeEngine, kv_block_bytes, poisson_arrivals)
@@ -239,9 +240,67 @@ def test_paged_prefix_reuse_bitwise():
     partial prefix hit) emits tokens bit-identical to a cold run in a fresh
     engine — shared blocks hold exactly the KV a cold prefill would write,
     and the CoW'd full-prompt tail snapshot replays the cached first token."""
+    _check_prefix_reuse(_fused_acfg())
+
+
+def _leaky_acfg():
+    """A fused ACU on a synthetic multiplier whose zero row depends on the
+    other operand, ``M[0, w] = 7 + w // 16``: a masked key or an empty
+    cache position contributes to every PV sum according to its V code."""
+    import dataclasses
+
+    from repro.core.acu import Acu, AcuMode
+    from repro.core.approx_ops import ApproxConfig
+    from repro.core.lut import build_lut
+    from repro.core.multipliers import make_exact
+
+    def leaky(a, w):
+        a, w = a.astype(jnp.int32), w.astype(jnp.int32)
+        return a * w + 7 + w // 16
+
+    mult = dataclasses.replace(make_exact(8), name="mul8s_leaky", fn=leaky)
+    acu = Acu(multiplier=mult, mode=AcuMode.LUT, lut=build_lut(mult),
+              use_pallas=True, fused=True)
+    assert acu.m00() == 7
+    return ApproxConfig(acu=acu)
+
+
+def test_paged_prefix_reuse_bitwise_biased():
+    """The prefix-cache contract under the biased multiplier."""
+    _check_prefix_reuse(_leaky_acfg())
+
+
+def test_paged_decode_ignores_stale_block_tail():
+    """A reused block's stale contents past the row's ``kv_len`` never
+    reach the logits, even where masked positions contribute ``M[0, v]``:
+    prefill writes positions 0-3 of physical block 2, decode writes 4, and
+    5-7 keep whatever a previous owner left there."""
     cfg = reduced_config("smollm-135m")
     params = init_params(KEY, cfg)
-    acfg = _fused_acfg()
+    acfg = _leaky_acfg()
+    pt = jnp.asarray([[2, 0, 0, 0]], jnp.int32)
+
+    def prefill_decode(cache):
+        _, cache = apply_model(params, jnp.asarray([[9, 2, 6, 5]], jnp.int32),
+                               cfg, acfg=acfg, cache=cache,
+                               cache_pos=jnp.asarray(0, jnp.int32),
+                               page_table=pt)
+        logits, _ = apply_model(params, jnp.asarray([[7]], jnp.int32), cfg,
+                                acfg=acfg, cache=cache,
+                                cache_pos=jnp.asarray([4], jnp.int32),
+                                decode=True, page_table=pt)
+        return logits
+
+    clean = init_paged_cache(cfg, 4, 8)
+    rng = np.random.default_rng(0)
+    stale = jax.tree.map(lambda pool: pool.at[:, :, 2].set(jnp.asarray(
+        rng.normal(size=pool[:, :, 2].shape) * 3, pool.dtype)), clean)
+    assert jnp.array_equal(prefill_decode(clean), prefill_decode(stale))
+
+
+def _check_prefix_reuse(acfg):
+    cfg = reduced_config("smollm-135m")
+    params = init_params(KEY, cfg)
     rng = np.random.default_rng(2)
     base = rng.integers(1, cfg.vocab_size, 20).astype(np.int32).tolist()
     ext = base + rng.integers(1, cfg.vocab_size, 5).astype(np.int32).tolist()
