@@ -64,6 +64,7 @@ def err_matmul_kernel(a: jnp.ndarray, w: jnp.ndarray, f: jnp.ndarray,
     grid = (M // bm, N // bn, K // bk)
     return pl.pallas_call(
         functools.partial(_kernel, offset=offset, rank=rank),
+        name="err_matmul_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

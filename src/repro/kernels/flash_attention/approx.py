@@ -262,6 +262,7 @@ def approx_flash_attention_kernel(q, k, v, lut, rowinfo, sqs, sks, svs,
                           seq_k_real=seq_k_real, d_real=d_real,
                           n_planes=n_planes, offset=offset, lo=lo, hi=hi,
                           causal=causal, window=window, softcap=softcap),
+        name="approx_flash_attention_kernel",
         grid=(bh, sq_p // bq),
         in_specs=[
             pl.BlockSpec((1, bq, dp), lambda b, i: (b, i, 0)),
@@ -408,6 +409,7 @@ def approx_flash_attention_paged_kernel(q, k_pool, v_pool, lut, rowinfo,
                           n_logical=n_logical, d_real=d_real,
                           n_planes=n_planes, offset=offset, lo=lo, hi=hi,
                           causal=causal, window=window, softcap=softcap),
+        name="approx_flash_attention_paged_kernel",
         grid=(bh, sq_p // bq),
         in_specs=[
             pl.BlockSpec((1, bq, dp), lambda b, i: (b, i, 0)),

@@ -95,6 +95,7 @@ def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return pl.pallas_call(
         functools.partial(_kernel, bq=bq, bk=bk, seq_k=sk, causal=causal,
                           window=window, softcap=softcap, scale=scale),
+        name="flash_attention_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),

@@ -175,6 +175,7 @@ def fused_lut_conv_kernel(xp: jnp.ndarray, wq: jnp.ndarray,
                           hi=hi, inner=inner, kh=kh, kw=kw, sh=sh, sw=sw,
                           dh=dh, dw=dw, bh=bh, wo=wo, c_pad_corr=c_pad_corr,
                           emit_acc=emit_acc),
+        name="fused_lut_conv_kernel",
         grid=grid,
         in_specs=[
             # the whole padded image streams in once per n (the block index
@@ -323,6 +324,7 @@ def fused_lut_conv_bwd_w_kernel(xp: jnp.ndarray, g: jnp.ndarray,
                           lo=lo, hi=hi, mc=mc, kh=kh, kw=kw, sh=sh, sw=sw,
                           dh=dh, dw=dw, bh=bh, wo=wo, n_copies=n_copies,
                           pad_m=(-bm) % mc),
+        name="fused_lut_conv_bwd_w_kernel",
         grid=grid,
         in_specs=[x_spec(k) for k in range(n_copies)] + [
             pl.BlockSpec((1, bh, wo, bn), lambda j, n, i: (n, i, 0, j)),
@@ -421,6 +423,7 @@ def fused_lut_conv_tiled_kernel(xp: jnp.ndarray, wq: jnp.ndarray,
                           sw=sw, dh=dh, dw=dw, bh=bh, wo=wo,
                           n_copies=n_copies, c_pad_corr=c_pad_corr,
                           emit_acc=emit_acc),
+        name="fused_lut_conv_tiled_kernel",
         grid=grid,
         in_specs=[x_spec(k) for k in range(n_copies)] + [
             pl.BlockSpec((kh * kw, c, bn), lambda n, i, j: (0, 0, j)),

@@ -116,10 +116,11 @@ def _bwd_kernel(a_ref, b_ref, lut_ref, as_ref, bs_ref, m00_ref, o_ref,
             emit_acc=emit_acc)
 
 
-def _gemm_call(kernel, lhs, rhs, operands, specs, *, bm: int, bk: int,
-               bn: int, interpret, emit_acc: bool):
-    """The shared ``pallas_call``: (i, j, k) grid over (M, N, K) tiles, the
-    whole padded table resident, int32 accumulator scratch."""
+def _gemm_call(kernel, lhs, rhs, operands, specs, *, name: str, bm: int,
+               bk: int, bn: int, interpret, emit_acc: bool):
+    """The shared ``pallas_call``, named ``name``: (i, j, k) grid over
+    (M, N, K) tiles, the whole padded table resident, int32 accumulator
+    scratch."""
     M, K = lhs.shape
     _, N = rhs.shape
     assert M % bm == 0 and K % bk == 0 and N % bn == 0 and bk % LANES == 0, (
@@ -127,6 +128,7 @@ def _gemm_call(kernel, lhs, rhs, operands, specs, *, bm: int, bk: int,
     lut = operands[0]
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=(M // bm, N // bn, K // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -162,7 +164,8 @@ def fused_lut_bwd_kernel(a: jnp.ndarray, b: jnp.ndarray, lut: jnp.ndarray,
         functools.partial(_bwd_kernel, offset=offset, lo=lo, hi=hi,
                           k_pad=k_pad, n_planes=n_planes, emit_acc=emit_acc),
         a, b, (lut, a_scale, b_scale, m00), [SMEM, SMEM, SMEM],
-        bm=bm, bk=bk, bn=bn, interpret=interpret, emit_acc=emit_acc)
+        name="fused_lut_bwd_kernel", bm=bm, bk=bk, bn=bn, interpret=interpret,
+        emit_acc=emit_acc)
 
 
 @functools.partial(jax.jit, static_argnames=("offset", "lo", "hi", "k_pad",
@@ -186,4 +189,5 @@ def fused_lut_dense_kernel(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
                           n_planes=n_planes, emit_acc=emit_acc),
         x, wq, (lut, x_scale, x_zp, m00, w_scale_row),
         [SMEM, SMEM, SMEM, pl.BlockSpec((1, bn), lambda i, j, k: (0, j))],
-        bm=bm, bk=bk, bn=bn, interpret=interpret, emit_acc=emit_acc)
+        name="fused_lut_dense_kernel", bm=bm, bk=bk, bn=bn, interpret=interpret,
+        emit_acc=emit_acc)

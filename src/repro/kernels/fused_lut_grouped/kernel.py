@@ -133,6 +133,7 @@ def fused_lut_grouped_kernel(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_kernel, offset=offset, lo=lo, hi=hi, k_pad=k_pad,
                           n_planes=n_planes, emit_acc=emit_acc),
+        name="fused_lut_grouped_kernel",
         grid=(G, mblocks, N // bn, K // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda g, m, n, k: (g * mblocks + m, k)),

@@ -49,6 +49,7 @@ def lut_matmul_kernel(a: jnp.ndarray, w: jnp.ndarray, lut: jnp.ndarray, *,
         f"shape {(M, K, N)} not divisible by tile {(bm, bk, bn)}")
     return pl.pallas_call(
         functools.partial(_kernel, offset=offset, n_planes=n_planes),
+        name="lut_matmul_kernel",
         grid=(M // bm, N // bn, K // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

@@ -38,6 +38,7 @@ def quantize_kernel(x: jnp.ndarray, scale: jnp.ndarray, zero_point: jnp.ndarray,
     hi = (1 << (bits - 1)) - 1
     return pl.pallas_call(
         functools.partial(_kernel, lo=lo, hi=hi),
+        name="quantize_kernel",
         grid=(n // block,),
         in_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),

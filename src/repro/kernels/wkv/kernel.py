@@ -64,6 +64,7 @@ def wkv_kernel(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, w: jnp.ndarray,
     u_spec = pl.BlockSpec((1, hd), lambda b: (b % n_heads, 0))
     return pl.pallas_call(
         functools.partial(_kernel, seq=t),
+        name="wkv_kernel",
         grid=grid,
         in_specs=[io_spec, io_spec, io_spec, io_spec, u_spec, st_spec],
         out_specs=[io_spec, st_spec],

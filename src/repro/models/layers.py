@@ -217,6 +217,7 @@ def gqa_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     return out.reshape(b, s_len, hq, d).astype(q.dtype)
 
 
+@jax.named_scope("attn")
 def attention_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig],
                     positions: Array, *, kv: Optional[tuple] = None,
                     cache=None, cache_pos: Optional[Array] = None,
@@ -377,6 +378,7 @@ def attention_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig],
 # MLPs
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mlp")
 def mlp_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig]) -> Array:
     """Gated (SwiGLU/GeGLU) or plain-GELU MLP, TP-sharded on the hidden dim."""
     if cfg.mlp_type in ("swiglu", "geglu"):
@@ -398,6 +400,7 @@ def embed(tokens: Array, table: Array) -> Array:
     return jnp.take(table, tokens, axis=0)
 
 
+@jax.named_scope("lm_head")
 def lm_head(x: Array, w: Array, acfg: Optional[ApproxConfig],
             softcap: Optional[float] = None) -> Array:
     logits = approx_dense(x, w, None, acfg)

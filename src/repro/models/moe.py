@@ -134,6 +134,7 @@ def dispatch_geometry(cfg, t: int) -> dict:
             "capacity_factor": cfg.moe_capacity}
 
 
+@jax.named_scope("moe")
 def moe_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig],
               *, return_stats: bool = False):
     """x: (B, S, D) -> (B, S, D), or ``(out, stats)`` with

@@ -261,9 +261,10 @@ def apply_model(params: dict, tokens: Array, cfg: ModelConfig, *,
     layer (the engine allocates blocks per slot, not per layer).
     """
     b, s = tokens.shape
-    x = L.embed(tokens, params["embed"])
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    with jax.named_scope("embed"):
+        x = L.embed(tokens, params["embed"])
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     x = shard(x, "batch", None, None)
     cp = jnp.asarray(cache_pos)
     # cache_pos may be a (B,) vector — continuous batching, every slot decodes
@@ -308,7 +309,8 @@ def apply_model(params: dict, tokens: Array, cfg: ModelConfig, *,
         # serving prefill: only the last position's logits are needed —
         # skips a (B, S, V) logits tensor and its GEMM
         x = x[:, -1:]
-    x = _norm(x, jax.tree.map(lambda a: a[0], params["final_norm"]), cfg)
+    with jax.named_scope("final_norm"):
+        x = _norm(x, jax.tree.map(lambda a: a[0], params["final_norm"]), cfg)
     head = params["embed"].T if cfg.tie_embed else params["lm_head"]
     logits = L.lm_head(x, head, acfg, softcap=cfg.softcap_final)
     return logits, new_cache
@@ -317,7 +319,8 @@ def apply_model(params: dict, tokens: Array, cfg: ModelConfig, *,
 def loss_fn(params, tokens, labels, cfg: ModelConfig,
             acfg: Optional[ApproxConfig] = None) -> Array:
     logits, _ = apply_model(params, tokens, cfg, acfg=acfg)
-    return L.cross_entropy(logits, labels, cfg.vocab_size)
+    with jax.named_scope("loss"):
+        return L.cross_entropy(logits, labels, cfg.vocab_size)
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,14 @@ Features (DESIGN.md §5):
   run exactly,
 * step-time watchdog hook (straggler posture),
 * QAT mode: the same loop fine-tunes through the approximate forward / exact
-  STE backward (paper Fig. 1 flow).
+  STE backward (paper Fig. 1 flow),
+* profiler spans on the host: each loop iteration is a ``repro.train.step``
+  step span holding ``repro.train.draw`` (the next batch), ``repro.train.wait``
+  (the host reading the loss, i.e. waiting for the device), and
+  ``repro.train.checkpoint`` / ``repro.train.restore`` when those run. They
+  record nothing unless a profiler trace is active. The device ops carry the
+  model's ``jax.named_scope`` names (``embed``, ``attn``, ``mlp``, ``moe``,
+  ``final_norm``, ``lm_head``, ``loss``) and ``optimizer`` for the update.
 """
 from __future__ import annotations
 
@@ -34,6 +41,7 @@ from typing import Callable, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.optim import damping as damping_lib
 from repro.optim.adamw import AdamW, SGD
@@ -134,7 +142,9 @@ class Trainer:
                 params, batch, n_micro)
             stats = {"micro_sqsum": micro_sqsum,
                      "gsq_big": damping_lib.tree_sqnorm(grads)}
-            new_params, new_state = self.opt.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = self.opt.update(grads, opt_state,
+                                                        params)
             return new_params, new_state, loss, stats
 
         donate = (0, 1) if self._donate else ()
@@ -179,7 +189,9 @@ class Trainer:
             # every worker and in the single-device oracle
             stats = {"local_sq": local_sq, "resid_sq": resid_sq,
                      "gsq_big": damping_lib.tree_sqnorm(mean)}
-            new_params, new_state = self.opt.update(mean, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = self.opt.update(mean, opt_state,
+                                                        params)
             return new_params, new_state, new_resid, loss, stats
 
         donate = (0, 1, 2) if self._donate else ()
@@ -273,73 +285,81 @@ class Trainer:
 
         failures = 0
         while step < n_steps:
-            n_micro, batch, batch_rows = self._next_batch(draw, damp)
-            t0 = time.monotonic()
-            try:
-                if fail_hook is not None:
-                    fail_hook(step)  # failure injection point (tests)
-                params, opt_state, loss, stats = self._run_step(
-                    params, opt_state, batch, n_micro)
-                loss = float(loss)
-            except Exception as e:  # noqa: BLE001 — node-failure surface
-                failures += 1
-                if failures > c.max_failures or not c.ckpt_dir:
-                    raise
-                self.saver.wait()   # in-flight snapshot becomes durable
-                restored = ckpt_lib.latest_step(c.ckpt_dir)
-                if restored is None:
-                    raise RuntimeError("failure before first checkpoint") from e
-                tree, man = ckpt_lib.restore(
-                    c.ckpt_dir, restored,
-                    jax.tree.map(lambda x: x,
-                                 self._ckpt_tree(params, opt_state)))
-                params, opt_state = self._unpack_ckpt(tree)
-                step = man["step"]
-                extra = man.get("extra", {})
-                back_to = int(extra.get("consumed", 0))
-                if damp is not None:
-                    damp = (damping_lib.DampingState.from_dict(
-                        extra["damping"]) if extra.get("damping") else
-                        damping_lib.init_state(c.damping))
-                # rewind: every batch drawn after the checkpoint replays, in
-                # draw order (replay_buf is append-ordered and never
-                # re-appends a replayed batch, so this filter is exact)
-                replay_pending = [(i, b) for i, b in replay_buf
-                                  if i >= back_to]
-                consumed = back_to
-                self.history.append(
-                    {"step": step,
-                     "event": f"restored after {type(e).__name__}"})
-                continue
-            dt = time.monotonic() - t0
-            step += 1
-            if step_hook is not None:   # eval/curve hook (benchmarks)
-                step_hook(step, params, consumed)
-            if damp is not None and step % c.damping.check_every == 0:
-                damp = self._damping_update(damp, stats, n_micro, batch_rows)
-            if c.step_timeout_s and dt > c.step_timeout_s:
-                self.history.append(
-                    {"step": step, "event": f"straggler: {dt:.1f}s"})
-            if step % c.log_every == 0 or step == n_steps:
-                h = {"step": step, "loss": loss, "dt": dt,
-                     "consumed": consumed}
-                if damp is not None:
-                    h.update(accum=damp.accum, b_noise=damp.b_noise)
-                self.history.append(h)
-            if c.ckpt_dir and (step % c.ckpt_every == 0 or step == n_steps):
-                extra_out = {"consumed": consumed}
-                if damp is not None:
-                    extra_out["damping"] = damp.to_dict()
-                saved_consumed[step] = consumed
-                if c.async_ckpt:
-                    self.saver.submit(c.ckpt_dir, step,
-                                      self._ckpt_tree(params, opt_state),
-                                      extra=extra_out, keep=c.keep)
-                else:
-                    ckpt_lib.save(c.ckpt_dir, step,
-                                  self._ckpt_tree(params, opt_state),
-                                  extra=extra_out, keep=c.keep)
-                trim_replay()
+            with StepTraceAnnotation("repro.train.step", step_num=step):
+                with TraceAnnotation("repro.train.draw"):
+                    n_micro, batch, batch_rows = self._next_batch(draw, damp)
+                t0 = time.monotonic()
+                try:
+                    if fail_hook is not None:
+                        fail_hook(step)  # failure injection point (tests)
+                    params, opt_state, loss, stats = self._run_step(
+                        params, opt_state, batch, n_micro)
+                    with TraceAnnotation("repro.train.wait"):
+                        loss = float(loss)
+                except Exception as e:  # noqa: BLE001 — node-failure surface
+                    failures += 1
+                    if failures > c.max_failures or not c.ckpt_dir:
+                        raise
+                    with TraceAnnotation("repro.train.restore"):
+                        self.saver.wait()   # in-flight snapshot is durable
+                        restored = ckpt_lib.latest_step(c.ckpt_dir)
+                        if restored is None:
+                            raise RuntimeError(
+                                "failure before first checkpoint") from e
+                        tree, man = ckpt_lib.restore(
+                            c.ckpt_dir, restored,
+                            jax.tree.map(lambda x: x,
+                                         self._ckpt_tree(params, opt_state)))
+                        params, opt_state = self._unpack_ckpt(tree)
+                        step = man["step"]
+                        extra = man.get("extra", {})
+                        back_to = int(extra.get("consumed", 0))
+                        if damp is not None:
+                            damp = (damping_lib.DampingState.from_dict(
+                                extra["damping"]) if extra.get("damping") else
+                                damping_lib.init_state(c.damping))
+                        # rewind: every batch drawn after the checkpoint
+                        # replays, in draw order (replay_buf is append-ordered
+                        # and never re-appends a replayed batch, so this
+                        # filter is exact)
+                        replay_pending = [(i, b) for i, b in replay_buf
+                                          if i >= back_to]
+                        consumed = back_to
+                    self.history.append(
+                        {"step": step,
+                         "event": f"restored after {type(e).__name__}"})
+                    continue
+                dt = time.monotonic() - t0
+                step += 1
+                if step_hook is not None:   # eval/curve hook (benchmarks)
+                    step_hook(step, params, consumed)
+                if damp is not None and step % c.damping.check_every == 0:
+                    damp = self._damping_update(damp, stats, n_micro,
+                                                batch_rows)
+                if c.step_timeout_s and dt > c.step_timeout_s:
+                    self.history.append(
+                        {"step": step, "event": f"straggler: {dt:.1f}s"})
+                if step % c.log_every == 0 or step == n_steps:
+                    h = {"step": step, "loss": loss, "dt": dt,
+                         "consumed": consumed}
+                    if damp is not None:
+                        h.update(accum=damp.accum, b_noise=damp.b_noise)
+                    self.history.append(h)
+                if c.ckpt_dir and (step % c.ckpt_every == 0
+                                   or step == n_steps):
+                    extra_out = {"consumed": consumed}
+                    if damp is not None:
+                        extra_out["damping"] = damp.to_dict()
+                    saved_consumed[step] = consumed
+                    with TraceAnnotation("repro.train.checkpoint"):
+                        snapshot = self._ckpt_tree(params, opt_state)
+                        if c.async_ckpt:
+                            self.saver.submit(c.ckpt_dir, step, snapshot,
+                                              extra=extra_out, keep=c.keep)
+                        else:
+                            ckpt_lib.save(c.ckpt_dir, step, snapshot,
+                                          extra=extra_out, keep=c.keep)
+                    trim_replay()
         self.saver.wait()
         self.consumed = consumed
         self.damp_state = damp
