@@ -214,7 +214,8 @@ class MatmulPlan:
     ``route`` names the callable ``fn`` runs, recorded where it was
     chosen. ``partition`` records the mesh partition the plan executes
     under (``None`` = single-device); the wrapped ``fn`` already contains
-    the ``shard_map`` — callers never change.
+    the ``shard_map`` — callers never change. A fused plan's ``tiles`` fill
+    as its kernel is traced (:func:`_tile_log`).
     """
 
     mode: AcuMode
@@ -224,16 +225,35 @@ class MatmulPlan:
     fn: Callable[..., Array]
     route: str
     partition: Optional[object] = None   # parallel.planner.GemmPartition
+    tiles: Optional[dict] = dataclasses.field(default=None, compare=False)
 
     def __call__(self, *args) -> Array:
         return self.fn(*args)
 
     def describe(self) -> dict:
         part = self.partition
-        return {"route": self.route, "mode": self.mode.value,
-                "bits": self.bits,
-                "partition": None if part is None else
-                    f"rows{part.rows}x cols{part.cols}x k{part.k}"}
+        d = {"route": self.route, "mode": self.mode.value,
+             "bits": self.bits,
+             "partition": None if part is None else
+                 f"rows{part.rows}x cols{part.cols}x k{part.k}"}
+        if self.tiles is not None:
+            d["tiles"] = dict(self.tiles)
+        return d
+
+
+def _tile_log(acu: Acu):
+    """``(tiles, log)`` for a fused forward or backward plan: ``log(m, k,
+    n)``, called where a kernel is traced with an (m, k) x (k, n) GEMM (one
+    shard's under a partition), enters that shape's ``(bm, bk, bn)`` in
+    ``tiles`` as ``"MxKxN"``."""
+    from repro.kernels.fused_lut_dense.ops import tiles as pick
+    from repro.kernels.lut_gather import lut_planes
+    planes, tiles = lut_planes(acu.lut), {}
+
+    def log(m: int, k: int, n: int) -> None:
+        tiles[f"{m}x{k}x{n}"] = pick(m, k, n, planes)
+
+    return tiles, log
 
 
 def _no_oracle_on_tpu(what: str, report) -> None:
@@ -333,12 +353,14 @@ def matmul_plan(acu: Acu, *, a_bits: Optional[int] = None,
     if fused and acu.mode == AcuMode.LUT and acu.use_pallas \
             and acu.lut is not None:
         from repro.kernels.fused_lut_dense import ops as fops
+        tiles, log = _tile_log(acu)
 
         def fused_call(x, wq, x_scale, x_zp, w_scale, *, emit_acc=False):
             # the host table goes in as is: plans are cached across jit
             # traces (a device constant made in one must not leak into
             # another), and the kernel wrapper reads its digit-plane count
             # from the concrete values
+            log(x.shape[0], *wq.shape)
             return fops.fused_lut_dense(x, wq, acu.lut,
                                         acu.offset, x_scale, x_zp, w_scale,
                                         bits=a_bits, interpret=acu.interpret,
@@ -351,7 +373,7 @@ def matmul_plan(acu: Acu, *, a_bits: Optional[int] = None,
                 ctx, partition, acu.m00())
         return MatmulPlan(mode=acu.mode, bits=acu.bits, use_pallas=True,
                           fused=True, fn=fn, route="fused_lut_dense",
-                          partition=partition)
+                          partition=partition, tiles=tiles)
 
     route, fn = _resolve_unfused(acu)
     if partition is not None:
@@ -361,11 +383,20 @@ def matmul_plan(acu: Acu, *, a_bits: Optional[int] = None,
 
 
 class MatmulBwdPlan(NamedTuple):
-    """The approximate STE backward GEMM pair and the route both run."""
+    """The approximate STE backward GEMM pair, the route both run and, for
+    the fused route, the tiles of the kernels traced so far (as
+    :attr:`MatmulPlan.tiles`)."""
 
     gx: Callable[..., Array]
     gw: Callable[..., Array]
     route: str
+    tiles: Optional[dict] = None
+
+    def describe(self) -> dict:
+        d = {"route": self.route}
+        if self.tiles is not None:
+            d["tiles"] = dict(self.tiles)
+        return d
 
 
 def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
@@ -373,7 +404,7 @@ def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
                     ) -> MatmulBwdPlan:
     """Resolve the *approximate* STE backward GEMM pair for one ACU.
 
-    Returns ``(gx_fn, gw_fn, route)``; each fn is
+    Returns ``(gx_fn, gw_fn, route, tiles)``; each fn is
     ``fn(a, b, sa, sb) -> f32 (M, N)`` computing the approximate GEMM of two **float** operands quantized
     per-tensor symmetric (zero-point 0 — gradients are zero-centred) with a
     single combined-scale dequant ``acc * (sa * sb)``. The caller computes
@@ -407,9 +438,11 @@ def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
     if fused and acu.mode == AcuMode.LUT and acu.use_pallas \
             and acu.lut is not None:
         from repro.kernels.fused_lut_dense import ops as fops
+        tiles, log = _tile_log(acu)
 
         def bwd_call(a, b, sa, sb, *, emit_acc=False):
             # host table as is: see fused_call in matmul_plan
+            log(*a.shape, b.shape[1])
             return fops.fused_lut_bwd(a, b, acu.lut, acu.offset,
                                       sa, sb, bits=a_bits,
                                       interpret=acu.interpret,
@@ -423,7 +456,8 @@ def matmul_bwd_plan(acu: Acu, *, a_bits: Optional[int] = None,
                 bwd_call, lambda *args: bwd_call(*args, emit_acc=True),
                 ctx, part, acu.m00())
 
-        return MatmulBwdPlan(route(gx_part), route(gw_part), "fused_lut_bwd")
+        return MatmulBwdPlan(route(gx_part), route(gw_part), "fused_lut_bwd",
+                             tiles)
 
     # unfused: quantize outside (full tensors, global scales), run the
     # mode's integer GEMM — sharded via the permuted partition when a mesh

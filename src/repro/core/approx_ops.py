@@ -79,13 +79,15 @@ def _affine_matmul_dequant(acc: Array, xqp: QParams, wqp: QParams) -> Array:
 
 
 _STE_CACHE: dict = {}
-_RESOLVED: dict = {}    # cache key -> describe() of the plan it resolved
+_RESOLVED: dict = {}    # cache key -> report of the plan it resolved
 
 
 def resolved_plans() -> list[dict]:
     """``describe()`` of every GEMM and attention plan this process has
-    resolved so far (GEMM entries add the STE backward's ``bwd_route``)."""
-    return list(_RESOLVED.values())
+    resolved so far. GEMM entries add the STE backward's ``bwd_route``;
+    fused routes list the ``(bm, bk, bn)`` of each kernel shape traced so
+    far under ``tiles`` (forward) and ``bwd_tiles`` (both backward GEMMs)."""
+    return [report() for report in _RESOLVED.values()]
 
 
 def _mesh_cache_key(ctx):
@@ -121,18 +123,21 @@ def _get_ste_fn(acu: Acu, a_bits: int, w_bits: int, fused: bool = False,
         return _STE_CACHE[key]
 
     plan = matmul_plan(acu, a_bits=a_bits, fused=fused, mesh=ctx or False)
-    bwd_route = "exact_f32"
+    bwd_report = lambda: {"bwd_route": "exact_f32"}
     if approx_bwd:
         from .acu import matmul_bwd_plan
-        gx_bwd, gw_bwd, bwd_route = matmul_bwd_plan(
-            acu, a_bits=a_bits, fused=fused, mesh=ctx or False)
+        bwd_plan = matmul_bwd_plan(acu, a_bits=a_bits, fused=fused,
+                                   mesh=ctx or False)
+        gx_bwd, gw_bwd = bwd_plan.gx, bwd_plan.gw
+        bwd_report = lambda: {f"bwd_{k}": v
+                              for k, v in bwd_plan.describe().items()}
     elif plan.partition is not None:
         from repro.parallel.acu_shard import bwd_gemms
         gx_gemm, gw_gemm = bwd_gemms(ctx, plan.partition)
     else:
         gx_gemm = lambda g, wf: g @ wf.T
         gw_gemm = lambda xf, g: xf.T @ g
-    _RESOLVED[key] = {"op": "gemm", **plan.describe(), "bwd_route": bwd_route}
+    _RESOLVED[key] = lambda: {"op": "gemm", **plan.describe(), **bwd_report()}
 
     @jax.custom_vjp
     def ste_matmul(x, w, xs, xz, ws, wz):
@@ -398,7 +403,7 @@ def _get_attn_plan(acu: Acu, a_bits: int, spec, ctx):
         return _STE_CACHE[key]
     plan = attn_plan(acu, spec, a_bits=a_bits, mesh=ctx or False)
     _STE_CACHE[key] = plan
-    _RESOLVED[key] = {"op": "attention", **plan.describe()}
+    _RESOLVED[key] = lambda: {"op": "attention", **plan.describe()}
     return plan
 
 
@@ -550,8 +555,9 @@ def _conv_bwd_fns(acu: Acu, plan, a_bits: int, ctx):
     banded = (plan.bwd_route == "banded" and acu.mode == AcuMode.LUT
               and acu.use_pallas and acu.lut is not None)
     if not banded:
-        gx_d, gw_d, _ = matmul_bwd_plan(acu, a_bits=a_bits,
-                                        fused=plan.fused, mesh=ctx or False)
+        bwd_plan = matmul_bwd_plan(acu, a_bits=a_bits, fused=plan.fused,
+                                   mesh=ctx or False)
+        gx_d, gw_d = bwd_plan.gx, bwd_plan.gw
 
         def gx_fn(g, wf, sg, sw):
             g2 = g.reshape(-1, cout).astype(jnp.float32)

@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import resolve_interpret, round_apart
 from jax.experimental.pallas import tpu as pltpu
 
 
@@ -170,7 +170,7 @@ def fused_lut_conv_kernel(xp: jnp.ndarray, wq: jnp.ndarray,
         f"conv tiling mismatch: C={c}/inner={inner}, Cout={cout}/bn={bn}, "
         f"Ho_pad={ho_pad}/bh={bh}")
     grid = (n, ho_pad // bh, cout // bn)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, offset=offset, n_codes=n_codes, lo=lo,
                           hi=hi, inner=inner, kh=kh, kw=kw, sh=sh, sw=sw,
                           dh=dh, dw=dw, bh=bh, wo=wo, c_pad_corr=c_pad_corr,
@@ -194,6 +194,7 @@ def fused_lut_conv_kernel(xp: jnp.ndarray, wq: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((c, hp, wp), jnp.int32)],
         interpret=resolve_interpret(interpret),
     )(xp, wq, lut_flat, x_scale, x_zp, w_scale_row)
+    return round_apart(out, resolve_interpret(interpret))
 
 
 def _bwd_w_kernel(*refs, offset: int, n_codes: int, lo: int, hi: int,
@@ -417,7 +418,7 @@ def fused_lut_conv_tiled_kernel(xp: jnp.ndarray, wq: jnp.ndarray,
         return pl.BlockSpec((1, c, s_rows, wp),
                             lambda n, i, j, k=k: (n, 0, i + k, 0))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_tiled_kernel, offset=offset, n_codes=n_codes,
                           lo=lo, hi=hi, inner=inner, kh=kh, kw=kw, sh=sh,
                           sw=sw, dh=dh, dw=dw, bh=bh, wo=wo,
@@ -438,3 +439,4 @@ def fused_lut_conv_tiled_kernel(xp: jnp.ndarray, wq: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((c, n_copies * s_rows, wp), jnp.int32)],
         interpret=resolve_interpret(interpret),
     )(*([xp] * n_copies), wq, lut_flat, x_scale, x_zp, w_scale_row)
+    return round_apart(out, resolve_interpret(interpret))

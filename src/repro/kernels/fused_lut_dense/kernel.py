@@ -36,8 +36,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lut_gather import LANES, SMEM, lut_gemm_streamed
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.lut_gather import (LANES, ONEHOT_WIDTH, ROW_BLOCK, SMEM,
+                                      lut_gemm_streamed)
+from repro.kernels.runtime import resolve_interpret, round_apart
+
+# scoped VMEM Mosaic gives a kernel call that asks for none (a v5e core has
+# 128 MiB); the tile picker (``ops.tiles``) keeps :func:`gemm_vmem_bytes`
+# under it
+DEFAULT_VMEM = 16 << 20
+
+
+def gemm_vmem_bytes(bm: int, bk: int, bn: int, planes: int) -> int:
+    """Working-set bytes of one dense forward or backward kernel at a tile
+    for a 256-code table of ``planes`` digit planes and the widest operands
+    either kernel takes, float32. Counts the pipelined blocks twice (double
+    buffering), the table, the accumulator scratch and the loop-carried
+    accumulator with its update, the piece's codes and the live
+    intermediates of one contraction chunk of the core
+    (:mod:`repro.kernels.lut_gather`): the gathered int8 planes of every
+    column chunk and one row block's one-hot."""
+    codes, onehot = 256, ONEHOT_WIDTH
+    return (2 * 4 * (bm * bk + bk * bn + bm * bn + 8 * bn)   # x, w, out, scale
+            + 2 * 4 * codes * codes                           # the table
+            + 3 * 4 * bm * bn                                 # accumulators
+            + 4 * LANES * (bm + bn)                           # a and b codes
+            + planes * codes * codes                          # packed planes
+            + planes * onehot * bn                            # gathered planes
+            + (1 + 4) * ROW_BLOCK * onehot)                   # one-hot
 
 
 def _finish(acc_ref, o_ref, m00_ref, scale, *, k_pad: int, emit_acc: bool):
@@ -126,7 +151,7 @@ def _gemm_call(kernel, lhs, rhs, operands, specs, *, name: str, bm: int,
     assert M % bm == 0 and K % bk == 0 and N % bn == 0 and bk % LANES == 0, (
         f"shape {(M, K, N)} not divisible by tile {(bm, bk, bn)}")
     lut = operands[0]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         name=name,
         grid=(M // bm, N // bn, K // bk),
@@ -142,6 +167,7 @@ def _gemm_call(kernel, lhs, rhs, operands, specs, *, name: str, bm: int,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=resolve_interpret(interpret),
     )(lhs, rhs, *operands)
+    return round_apart(out, resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("offset", "lo", "hi", "k_pad",

@@ -14,29 +14,50 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.lut_gather import round_up, table_operands
+from repro.kernels.lut_gather import LANES, round_up, table_operands
 
-from .kernel import fused_lut_bwd_kernel, fused_lut_dense_kernel
+from .kernel import (DEFAULT_VMEM, fused_lut_bwd_kernel,
+                     fused_lut_dense_kernel, gemm_vmem_bytes)
 
 
-def _tiles(M: int, K: int, N: int, bm: int, bk: int):
-    """Tile sizes and the padding that makes every dim a tile multiple.
+TILE_CAP = 640           # most rows or columns of one output tile
 
-    M tiles cap at 128 and shrink to the 8-row multiple above a short M
-    (decode batches); N tiles are the 128-lane width of the LUT-GEMM core's
-    gathers; K pads to 128 and runs as one grid step when the whole row
-    strip fits VMEM comfortably, otherwise in a k-tile that divides it."""
-    bm = min(bm, 128, round_up(M, 8))
-    bn = 128
-    pm, pk, pn = (-M) % bm, (-K) % 128, (-N) % bn
-    kp = K + pk
-    bk = kp if kp <= 512 else (bk if kp % bk == 0 else 128)
-    return (bm, bk, bn), (pm, pk, pn)
+
+def _widths(dim: int) -> list[int]:
+    """The multiples of 128 up to ``TILE_CAP`` that divide the 128-padded
+    ``dim``."""
+    d = round_up(dim, LANES)
+    return [t for t in range(LANES, TILE_CAP + 1, LANES) if d % t == 0]
+
+
+def tiles(M: int, K: int, N: int, planes: int) -> tuple[int, int, int]:
+    """The (bm, bk, bn) tile the dense forward and backward kernels run for
+    an (M, K) x (K, N) GEMM with a table of ``planes`` digit planes.
+
+    The core builds its gathered planes once per tile column chunk and its
+    one-hot once per row block, so wide tiles spread that work over more
+    MXU products: ``bm`` and ``bn`` are multiples of 128 up to ``TILE_CAP``
+    that divide the padded dims, the pair of largest area (then rows) whose
+    VMEM model fits the default scoped VMEM. With the 2-plane tables of
+    the registered multipliers that is the widest of each. A short M
+    (decode, M <= 128) keeps one tile of ``round_up(M, 8)`` rows. K runs
+    as one grid step up to 512, otherwise in 256- or 128-wide steps that
+    divide it."""
+    kp = round_up(K, LANES)
+    bk = kp if kp <= 512 else (256 if kp % 256 == 0 else LANES)
+    rows = [round_up(M, 8)] if M <= LANES else _widths(M)
+    _, bm, bn = max((bm * bn, bm, bn) for bm in rows for bn in _widths(N)
+                    if gemm_vmem_bytes(bm, bk, bn, planes) <= DEFAULT_VMEM)
+    return bm, bk, bn
+
+
+def _padding(M: int, K: int, N: int, tile) -> tuple[int, int, int]:
+    bm, _, bn = tile
+    return (-M) % bm, (-K) % LANES, (-N) % bn
 
 
 def fused_lut_dense(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
                     offset: int, x_scale, x_zp, w_scale, *, bits: int = 8,
-                    bm: int = 128, bk: int = 256,
                     interpret: bool | None = None,
                     emit_acc: bool = False) -> jnp.ndarray:
     """Fused approximate dense forward.
@@ -62,7 +83,8 @@ def fused_lut_dense(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
     xz = jnp.asarray(x_zp, jnp.float32).reshape(1)
     ws = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32).reshape(1, -1),
                           (1, N))
-    (bm, bk, bn), (pm, pk, pn) = _tiles(M, K, N, bm, bk)
+    bm, bk, bn = tile = tiles(M, K, N, n_planes)
+    pm, pk, pn = _padding(M, K, N, tile)
     if pm or pk or pn:
         x = jnp.pad(x, ((0, pm), (0, pk)))
         wq = jnp.pad(wq, ((0, pk), (0, pn)))
@@ -76,7 +98,6 @@ def fused_lut_dense(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
 
 def fused_lut_bwd(a: jnp.ndarray, b: jnp.ndarray, lut: jnp.ndarray,
                   offset: int, a_scale, b_scale, *, bits: int = 8,
-                  bm: int = 128, bk: int = 256,
                   interpret: bool | None = None,
                   emit_acc: bool = False) -> jnp.ndarray:
     """Fused approximate backward GEMM: quantize BOTH float operands
@@ -98,7 +119,8 @@ def fused_lut_bwd(a: jnp.ndarray, b: jnp.ndarray, lut: jnp.ndarray,
     hi = (1 << (bits - 1)) - 1
     sa = jnp.asarray(a_scale, jnp.float32).reshape(1)
     sb = jnp.asarray(b_scale, jnp.float32).reshape(1)
-    (bm, bk, bn), (pm, pk, pn) = _tiles(M, K, N, bm, bk)
+    bm, bk, bn = tile = tiles(M, K, N, n_planes)
+    pm, pk, pn = _padding(M, K, N, tile)
     if pm or pk or pn:
         a = jnp.pad(a, ((0, pm), (0, pk)))
         b = jnp.pad(b, ((0, pk), (0, pn)))

@@ -35,9 +35,12 @@ def unfused_oracle(x, w, xqp, wqp, acu=ACU):
 
 
 @pytest.mark.parametrize("shape", [(8, 16, 8), (128, 128, 128), (130, 70, 50),
-                                   (1, 257, 3), (256, 8, 384), (33, 64, 129)])
+                                   (1, 257, 3), (256, 8, 384), (33, 64, 129),
+                                   (640, 200, 384)])
 def test_fused_matches_oracle_shapes(shape):
-    """Shape sweep incl. non-divisible M/K/N; per-channel weight scales."""
+    """Shape sweep incl. non-divisible M/K/N; per-channel weight scales.
+    (640, 200, 384) runs one (640, 256, 384) tile: five one-hot row blocks
+    against three gathered column chunks, two K pieces."""
     M, K, N = shape
     rng = np.random.default_rng(M * K + N)
     x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
@@ -219,7 +222,8 @@ def _bwd_operands(m, k, n, seed):
 
 
 @pytest.mark.parametrize("shape", [(1, 1, 1), (8, 128, 8), (33, 257, 5),
-                                   (64, 96, 32), (130, 70, 129)])
+                                   (64, 96, 32), (130, 70, 129),
+                                   (640, 200, 384)])
 def test_fused_bwd_matches_ref_shapes(shape):
     """Backward-flavor kernel (both operands quantized in-kernel, per-tensor
     symmetric) vs its O(MKN) reference, odd and divisible M/K/N, eager and
@@ -241,6 +245,28 @@ def test_fused_bwd_k_pad_correction_biased_m00():
                             bits=8)
     out = fused_lut_bwd(a, b, _BIASED_LUT, 128, sa, sb, bits=8,
                         interpret=True)
+    assert jnp.array_equal(out, ref)
+
+
+def test_wide_tiles_biased_m00_forward_and_backward():
+    """A (256, 384) tile (two one-hot row blocks, three column chunks) with
+    K = 200 padded to 256: both kernels subtract the 56 padded ks' M[0, 0]
+    = 7 of the biased multiplier in integer space, bitwise."""
+    from repro.kernels.fused_lut_dense.ops import tiles
+    from repro.kernels.lut_gather import lut_planes
+    assert tiles(256, 200, 384, lut_planes(_BIASED_LUT)) == (256, 256, 384)
+    a, b, sa, sb = _bwd_operands(256, 200, 384, seed=21)
+    ref = fused_lut_bwd_ref(a, b, _BIASED_LUT.reshape(-1), 128, 256, sa, sb,
+                            bits=8)
+    out = fused_lut_bwd(a, b, _BIASED_LUT, 128, sa, sb, bits=8,
+                        interpret=True)
+    assert jnp.array_equal(out, ref)
+    wq = jnp.asarray(np.random.default_rng(22).integers(-128, 128, (200, 384)),
+                     jnp.int32)
+    out = fused_lut_dense(a, wq, _BIASED_LUT, 128, 0.04, 2.0, 0.01, bits=8,
+                          interpret=True)
+    ref = fused_lut_dense_ref(a, wq, _BIASED_LUT.reshape(-1), 128, 256, 0.04,
+                              2.0, 0.01, bits=8)
     assert jnp.array_equal(out, ref)
 
 
@@ -302,3 +328,107 @@ def test_ste_approx_bwd_fused_equals_unfused(shape):
     g2x, g2w = jax.jit(jax.grad(loss(c1), argnums=(0, 1)))(x, w)
     assert jnp.array_equal(g1x, g2x)
     assert jnp.array_equal(g1w, g2w)
+
+
+# ---------------------------------------------------------------------------
+# tile choice (ops.tiles) and its VMEM model
+# ---------------------------------------------------------------------------
+
+from repro.kernels.fused_lut_dense.kernel import DEFAULT_VMEM
+from repro.kernels.fused_lut_dense.ops import gemm_vmem_bytes, tiles
+
+D, F, V = 576, 1536, 49152                      # smollm-135m widths
+# every GEMM of a smollm-135m layer and head at M = 2048 tokens: forward
+# (M, K, N), then the STE backward's gx (M, N, K) and gw (K, M, N)
+_SMOLLM = [(2048, k, n) for k, n in ((D, D), (D, 192), (D, F), (F, D),
+                                     (D, V))]
+_SMOLLM += [(m, n, k) for m, k, n in _SMOLLM[:5]] + \
+           [(k, m, n) for m, k, n in _SMOLLM[:5]]
+
+
+def _widest(dim):
+    """The widest multiple of 128 up to 640 that divides the padded dim."""
+    d = -(-dim // 128) * 128
+    return max(t for t in range(128, 641, 128) if d % t == 0)
+
+
+@pytest.mark.parametrize("planes", [2, 4])
+@pytest.mark.parametrize("shape", _SMOLLM + [(4, D, D), (8, D, V), (130, 70, 50),
+                                             (1, 1, 1), (1000, 300, 700),
+                                             (640, 512, 640)])
+def test_tiles_divide_and_fit(shape, planes):
+    """Each tile divides its padded dim, the contraction tile is a multiple
+    of 128, a short M keeps one 8-row-aligned tile, and the VMEM model of
+    the tile stays under the scoped VMEM a kernel gets by default."""
+    M, K, N = shape
+    bm, bk, bn = tiles(M, K, N, planes)
+    ceil = lambda x, m: -(-x // m) * m
+    assert ceil(M, bm) % bm == 0 and bm % 8 == 0
+    assert ceil(K, 128) % bk == 0 and bk % 128 == 0
+    assert ceil(N, 128) % bn == 0 and bn % 128 == 0
+    if M <= 128:
+        assert bm == ceil(M, 8)
+    else:
+        assert bm % 128 == 0 and ceil(M, 128) % bm == 0
+    assert gemm_vmem_bytes(bm, bk, bn, planes) <= DEFAULT_VMEM
+
+
+def test_tiles_amortize_at_smollm_shapes():
+    """The cells' GEMMs run wide tiles: 512 rows of M = 2048, the whole
+    padded 640 of d_model = 576 on either side, 512 columns of 1536 and of
+    the 49,152-token head."""
+    assert tiles(2048, D, F, 2) == (512, 128, 512)
+    assert tiles(2048, D, D, 2) == (512, 128, 640)
+    assert tiles(2048, D, 192, 2) == (512, 128, 256)
+    assert tiles(2048, F, D, 2) == (512, 256, 640)
+    assert tiles(2048, D, V, 2) == (512, 128, 512)
+    assert tiles(D, 2048, F, 2) == (640, 256, 512)       # gw of gate/up
+    assert tiles(F, 2048, D, 2) == (512, 256, 640)       # gw of down
+    assert tiles(4, D, D, 2) == (8, 128, 640)            # decode
+
+
+def test_two_plane_tiles_fit_the_default_scoped_vmem():
+    """With the 2-plane mul8s_1L2H table every smollm-135m GEMM runs the
+    widest tile of each dim within the default scoped VMEM; a 4-plane table
+    narrows a tile only where the model needs it: of (640, 256, 640) at the
+    gw of the 576-wide projections, the 640 rows are kept."""
+    for M, K, N in _SMOLLM:
+        bm, _, bn = tiles(M, K, N, 2)
+        assert (bm, bn) == (_widest(M), _widest(N))
+    assert DEFAULT_VMEM < gemm_vmem_bytes(640, 256, 640, 4)
+    assert tiles(D, 2048, D, 4) == (640, 256, 128)
+    assert tiles(D, 2048, F, 4) == tiles(D, 2048, F, 2) == (640, 256, 512)
+
+
+def test_plan_reports_tiles():
+    """Fused forward and backward plans report the (bm, bk, bn) of each
+    kernel shape traced, and ``resolved_plans`` shows them; unfused plans
+    report none."""
+    from repro.core.acu import matmul_bwd_plan
+    from repro.core.approx_ops import resolved_plans
+    S = jax.ShapeDtypeStruct
+    f32, i32 = jnp.float32, jnp.int32
+    acu_f = make_acu("mul8s_1L2H", AcuMode.LUT, use_pallas=True, fused=True)
+    plan = matmul_plan(acu_f, mesh=False)
+    assert plan.describe()["tiles"] == {}
+    jax.eval_shape(plan, S((2048, D), f32), S((D, F), i32), S((), f32),
+                   S((), f32), S((F,), f32))
+    assert plan.describe()["tiles"] == {f"2048x{D}x{F}": (512, 128, 512)}
+    assert "tiles" not in matmul_plan(ACU_PALLAS, fused=False,
+                                      mesh=False).describe()
+    bwd = matmul_bwd_plan(acu_f, mesh=False)
+    jax.eval_shape(bwd.gx, S((2048, F), f32), S((F, D), f32), 1.0, 1.0)
+    jax.eval_shape(bwd.gw, S((D, 2048), f32), S((2048, F), f32), 1.0, 1.0)
+    assert bwd.describe() == {"route": "fused_lut_bwd", "tiles": {
+        f"2048x{F}x{D}": (512, 256, 640), f"{D}x2048x{F}": (640, 256, 512)}}
+    # the STE GEMM's report, through the process's resolved plans
+    cfg = ApproxConfig(acu=acu_f, approx_bwd=True)
+    xqp, wqp = QParams(1.0, 0.0, 8), QParams(1.0, 0.0, 8, axis=1)
+    jax.eval_shape(jax.grad(lambda x, w: approx_matmul(x, w, cfg, xqp,
+                                                       wqp).sum()),
+                   S((136, 200), f32), S((200, 384), f32))
+    rep = [p for p in resolved_plans() if p.get("bwd_tiles") and
+           "200x136x384" in p["bwd_tiles"]]
+    assert rep and rep[-1]["tiles"] == {"136x200x384": (256, 256, 384)}
+    assert rep[-1]["bwd_tiles"] == {"136x384x200": (256, 384, 256),
+                                    "200x136x384": (256, 256, 384)}

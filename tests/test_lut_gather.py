@@ -56,6 +56,22 @@ def test_lut_gemm_matches_take(n, planes):
     assert jnp.array_equal(got, _take_ref(a, b, tab))
 
 
+@pytest.mark.parametrize("m,k", [(200, 20), (136, 150)])
+@pytest.mark.parametrize("planes", [2, 4])
+def test_lut_gemm_row_blocks_match_take(m, k, planes):
+    """More rows than one one-hot block (a short last block) against N =
+    256 (two gathered column chunks): every (row block, column chunk)
+    accumulator sums the same integers as the gather; K = 150 runs a full
+    128-wide piece and a 22-index one with a 6-index tail chunk."""
+    tab = _table(256, planes, seed=10 + planes)
+    lut, n_codes = pad_lut(tab)
+    a, b = _codes(m, k, 256, n_codes, seed=m + k)
+    got = jax.jit(lambda a, b, t: lut_gemm(a, b, t, n_planes=planes))(
+        a, b, lut)
+    assert got.shape == (m, 256)
+    assert jnp.array_equal(got, _take_ref(a, b, tab))
+
+
 @pytest.mark.parametrize("n_codes,planes", [(256, 2), (256, 4), (16, 1)])
 def test_lut_gemm_traced_table(n_codes, planes):
     """A table that arrives as a traced jit argument has no values to read:

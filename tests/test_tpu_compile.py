@@ -11,12 +11,14 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.acu import make_acu
 
 D, F, HQ, HKV, HD = 576, 1536, 9, 3, 64        # smollm-135m widths
+V = 49152                                       # its vocabulary
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +60,12 @@ def _bwd(lut, off):
                                        interpret=False))
 
 
+def _bwd_4planes(lut, off):
+    # entries past three signed base-256 digits: a table of four planes,
+    # the widest working set a tile can hold
+    return _bwd((np.asarray(lut, np.int64) * 40000).astype(np.int32), off)
+
+
 def _attn(lut, off):
     from repro.kernels.flash_attention.approx import approx_flash_attention
     return (lambda q, k, v: approx_flash_attention(
@@ -85,6 +93,19 @@ CASES = {
     "fused_lut_dense_decode": (_dense, [((4, D), f32), ((D, D), i32),
                                         ((D,), f32)]),
     "fused_lut_bwd": (_bwd, [((256, F), f32), ((F, D), f32)]),
+    # the benchmark cells' shapes at M = 2048 tokens: the widest tiles
+    "fused_lut_dense_cell": (_dense, [((2048, D), f32), ((D, F), i32),
+                                      ((F,), f32)]),
+    "fused_lut_dense_head": (_dense, [((2048, D), f32), ((D, V), i32),
+                                      ((V,), f32)]),
+    "fused_lut_bwd_gw": (_bwd, [((D, 2048), f32), ((2048, F), f32)]),
+    # four planes: (640, 256, 640) would pass the default scoped VMEM by
+    # the tile model, so the 576-wide gw narrows to (640, 256, 128); the
+    # gate's (640, 256, 512) is kept
+    "fused_lut_bwd_gw_4planes": (_bwd_4planes, [((D, 2048), f32),
+                                                ((2048, D), f32)]),
+    "fused_lut_bwd_gw_gate_4planes": (_bwd_4planes, [((D, 2048), f32),
+                                                     ((2048, F), f32)]),
     "fused_lut_grouped": (_grouped, [((8, 32, D), f32), ((4, D, F), i32),
                                      ((4, F), f32), ((8,), i32)]),
     "approx_flash_attention": (_attn, [((HQ, 128, HD), f32),
