@@ -46,6 +46,7 @@ class ModelConfig:
     # blocks / norms
     mlp_type: str = "swiglu"           # swiglu | geglu | gelu
     norm: str = "rms"                  # rms | rms1p | ln
+    norm_eps: float = 1e-6             # every RMSNorm's epsilon, QK-norm too
     post_norm: bool = False            # gemma2 sandwich norms
     parallel_block: bool = False       # command-r style
     tie_embed: bool = False
@@ -54,7 +55,9 @@ class ModelConfig:
     # MoE
     n_experts: int = 0
     moe_top_k: int = 0
-    moe_capacity: float = 1.25
+    # capacity factor; None is dropless: each buffer holds a whole block
+    moe_capacity: Optional[float] = 1.25
+    moe_norm_topk: bool = True         # renormalise the top-k weights to 1
     # §Perf hillclimb #1: shard the dispatch capacity dim over `data`
     # (token-parallel expert compute). False reproduces the replicated-
     # dispatch baseline recorded in EXPERIMENTS.md.

@@ -83,8 +83,9 @@ _RESOLVED: dict = {}    # cache key -> report of the plan it resolved
 
 
 def resolved_plans() -> list[dict]:
-    """``describe()`` of every GEMM and attention plan this process has
-    resolved so far. GEMM entries add the STE backward's ``bwd_route``;
+    """``describe()`` of every GEMM, grouped expert GEMM (``op``
+    ``grouped_gemm``) and attention plan this process has resolved so far.
+    GEMM entries add the STE backward's ``bwd_route``;
     fused routes list the ``(bm, bk, bn)`` of each kernel shape traced so
     far under ``tiles`` (forward) and ``bwd_tiles`` (both backward GEMMs)."""
     return [report() for report in _RESOLVED.values()]
@@ -266,6 +267,7 @@ def _get_grouped_ste_fn(acu: Acu, a_bits: int, w_bits: int,
 
     plan = grouped_plan(acu, spec, a_bits=a_bits, mesh=ctx or False,
                         route=route)
+    _RESOLVED[key] = lambda: {"op": "grouped_gemm", **plan.describe()}
     E, C, nb = spec.n_experts, spec.cap, spec.n_blocks
     if plan.route != "fused_grouped":
         # per-expert vmapped composition (single-device inner plan — the

@@ -243,15 +243,16 @@ def attention_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig],
     """
     b, s_len, _ = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = approx_dense(x, p["wq"], p.get("bq"), acfg).reshape(b, s_len, h, hd)
     src = x if kv is None else kv
     t0 = src.shape[1]
-    k = approx_dense(src, p["wk"], p.get("bk"), acfg).reshape(b, t0, hkv, hd)
+    q = approx_dense(x, p["wq"], p.get("bq"), acfg)
+    k = approx_dense(src, p["wk"], p.get("bk"), acfg)
+    if cfg.qk_norm:  # over the whole (h * hd)-wide projection, before heads
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    q = q.reshape(b, s_len, h, hd)
+    k = k.reshape(b, t0, hkv, hd)
     v = approx_dense(src, p["wv"], p.get("bv"), acfg).reshape(b, t0, hkv, hd)
-
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
 
     if kv is None and cfg.rope != "none":
         if cfg.rope == "mrope":

@@ -1,5 +1,13 @@
 """Mixture-of-Experts layer: top-k router + capacity-based scatter dispatch.
 
+``cfg.moe_capacity`` is a capacity factor (assignments past it are
+dropped), or None for dropless routing: every expert's buffer then holds a
+whole dispatch block, E times the block's tokens in rows, k/E of them live
+(docs/moe.md). ``cfg.moe_norm_topk`` renormalises the top-k weights to sum
+to 1 (Granite) or keeps the softmax probabilities (OLMoE). The layer's
+parts run under ``jax.named_scope``s ``route``, ``dispatch``, ``experts``
+and ``combine`` inside ``moe``.
+
 Dispatch is scatter/gather (not GShard one-hot einsum): a (T, E, C) one-hot
 dispatch tensor is O(T^2)-ish at LM scale, while the scatter form moves
 exactly T*k rows.
@@ -37,15 +45,17 @@ from repro.parallel.sharding import current_mesh_context, shard
 Array = jnp.ndarray
 
 
-def _route(xf: Array, router: Array, k: int):
-    """Router products: full softmax probs (T, E) plus renormalized top-k
-    weights/indices (T, k). One softmax serves both dispatch and the
-    load-balancing aux loss (``moe_block`` stats) — callers reuse these
-    instead of re-running the router."""
+def _route(xf: Array, router: Array, k: int, norm_topk: bool = True):
+    """Router products: full softmax probs (T, E) plus top-k weights/indices
+    (T, k), the weights renormalized to sum to 1 when ``norm_topk`` (else
+    the softmax probabilities themselves). One softmax serves both dispatch
+    and the load-balancing aux loss (``moe_block`` stats) — callers reuse
+    these instead of re-running the router."""
     gate_logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)
     probs = jax.nn.softmax(gate_logits, axis=-1)               # (T, E)
     top_p, top_e = jax.lax.top_k(probs, k)                     # (T, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if norm_topk:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
     return probs, top_p, top_e
 
 
@@ -120,6 +130,16 @@ def _dispatch_blocks(cfg, t: int) -> int:
     return max(nb, 1)
 
 
+def _capacity(cfg, tb: int) -> int:
+    """Rows of each expert's buffer in a block of ``tb`` tokens. Dropless
+    (``moe_capacity`` None) holds the whole block: top-k picks distinct
+    experts, so no expert gets more than ``tb`` assignments."""
+    if cfg.moe_capacity is None:
+        return tb
+    return int(max(1, round(tb * cfg.moe_top_k / cfg.n_experts
+                            * cfg.moe_capacity)))
+
+
 def dispatch_geometry(cfg, t: int) -> dict:
     """Static dispatch geometry for ``t`` tokens under the active mesh
     context: resolved block count (after the divisibility fallback), tokens
@@ -128,10 +148,10 @@ def dispatch_geometry(cfg, t: int) -> dict:
     e, k = cfg.n_experts, cfg.moe_top_k
     nb = _dispatch_blocks(cfg, t)
     tb = t // nb
-    cap = int(max(1, round(tb * k / e * cfg.moe_capacity)))
-    return {"n_blocks": nb, "tokens_per_block": tb, "capacity": cap,
-            "n_experts": e, "top_k": k,
-            "capacity_factor": cfg.moe_capacity}
+    return {"n_blocks": nb, "tokens_per_block": tb,
+            "capacity": _capacity(cfg, tb), "n_experts": e, "top_k": k,
+            "capacity_factor": cfg.moe_capacity,
+            "dropless": cfg.moe_capacity is None}
 
 
 @jax.named_scope("moe")
@@ -152,49 +172,55 @@ def moe_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig],
     e, k = cfg.n_experts, cfg.moe_top_k
     t = b * s
     xf = x.reshape(t, d)
-    probs, top_p, top_e = _route(xf, p["router"], k)
+    with jax.named_scope("route"):
+        probs, top_p, top_e = _route(xf, p["router"], k, cfg.moe_norm_topk)
 
     nb = _dispatch_blocks(cfg, t)
     tb = t // nb                 # tokens per block
-    cap = int(max(1, round(tb * k / e * cfg.moe_capacity)))
+    cap = _capacity(cfg, tb)
 
-    # ---- block-local slot assignment -----------------------------------
-    flat_e = top_e.reshape(nb, tb * k)                         # (nb, TBk)
-    onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)        # (nb, TBk, E)
-    onehot = shard(onehot, "expert_blocks", None, None)
-    pos_in_e = jnp.cumsum(onehot, axis=1) - 1                  # within block
-    slot = jnp.take_along_axis(pos_in_e, flat_e[..., None], axis=2)[..., 0]
-    keep = slot < cap                                          # (nb, TBk)
-    dest = jnp.where(keep, flat_e * cap + slot, e * cap)       # (nb, TBk)
+    with jax.named_scope("dispatch"):
+        # ---- block-local slot assignment -------------------------------
+        flat_e = top_e.reshape(nb, tb * k)                     # (nb, TBk)
+        onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)    # (nb, TBk, E)
+        onehot = shard(onehot, "expert_blocks", None, None)
+        pos_in_e = jnp.cumsum(onehot, axis=1) - 1              # within block
+        slot = jnp.take_along_axis(pos_in_e, flat_e[..., None], axis=2)[..., 0]
+        keep = slot < cap                                      # (nb, TBk)
+        dest = jnp.where(keep, flat_e * cap + slot, e * cap)   # (nb, TBk)
 
-    # live rows per capacity buffer: slots 0..count-1 are occupied (cumsum
-    # order packs kept tokens densely) — the grouped GEMM's groupinfo
-    counts = jnp.minimum(onehot.sum(axis=1), cap)              # (nb, E)
+        # live rows per capacity buffer: slots 0..count-1 are occupied
+        # (cumsum order packs kept tokens densely) — the grouped GEMM's
+        # groupinfo
+        counts = jnp.minimum(onehot.sum(axis=1), cap)          # (nb, E)
 
-    # scatter token indices into per-block buffers (trash slot at the end)
-    tok_in_block = jnp.arange(tb * k, dtype=jnp.int32) // k    # (TBk,)
-    idx_buf = jnp.zeros((nb, e * cap + 1), jnp.int32)
-    idx_buf = idx_buf.at[jnp.arange(nb)[:, None], dest].set(tok_in_block[None] + 1)
-    idx_buf = idx_buf[:, :-1]                                  # (nb, E*cap)
+        # scatter token indices into per-block buffers (trash slot at end)
+        tok_in_block = jnp.arange(tb * k, dtype=jnp.int32) // k
+        idx_buf = jnp.zeros((nb, e * cap + 1), jnp.int32)
+        idx_buf = idx_buf.at[jnp.arange(nb)[:, None], dest].set(
+            tok_in_block[None] + 1)
+        idx_buf = idx_buf[:, :-1]                              # (nb, E*cap)
 
-    # gather rows (block-local): xfb (nb, TB, D) -> xe (nb, E, cap, D)
-    xfb = xf.reshape(nb, tb, d)
-    xfb = shard(xfb, "expert_blocks", None, None)
-    xe = jnp.take_along_axis(
-        xfb, jnp.maximum(idx_buf - 1, 0)[..., None], axis=1)
-    xe = xe * (idx_buf > 0)[..., None].astype(x.dtype)
-    xe = xe.reshape(nb, e, cap, d)
-    xe = shard(xe, "expert_blocks", "experts", None, None)
+        # gather rows (block-local): xfb (nb, TB, D) -> xe (nb, E, cap, D)
+        xfb = xf.reshape(nb, tb, d)
+        xfb = shard(xfb, "expert_blocks", None, None)
+        xe = jnp.take_along_axis(
+            xfb, jnp.maximum(idx_buf - 1, 0)[..., None], axis=1)
+        xe = xe * (idx_buf > 0)[..., None].astype(x.dtype)
+        xe = xe.reshape(nb, e, cap, d)
+        xe = shard(xe, "expert_blocks", "experts", None, None)
 
-    ye = _expert_ffn(xe, p, cfg, acfg, ("expert_blocks",), counts=counts)
-    ye = shard(ye, "expert_blocks", "experts", None, None)
+    with jax.named_scope("experts"):
+        ye = _expert_ffn(xe, p, cfg, acfg, ("expert_blocks",), counts=counts)
+        ye = shard(ye, "expert_blocks", "experts", None, None)
 
-    # combine (block-local gather + routed weights)
-    yeb = ye.reshape(nb, e * cap, d)
-    src = jnp.where(keep, flat_e * cap + slot, 0)              # (nb, TBk)
-    yk = jnp.take_along_axis(yeb, src[..., None], axis=1)      # (nb, TBk, D)
-    yk = jnp.where(keep[..., None], yk, 0.0).reshape(t, k, d)
-    out = (yk * top_p[:, :, None].astype(yk.dtype)).sum(axis=1)
+    with jax.named_scope("combine"):
+        # block-local gather + routed weights
+        yeb = ye.reshape(nb, e * cap, d)
+        src = jnp.where(keep, flat_e * cap + slot, 0)          # (nb, TBk)
+        yk = jnp.take_along_axis(yeb, src[..., None], axis=1)  # (nb, TBk, D)
+        yk = jnp.where(keep[..., None], yk, 0.0).reshape(t, k, d)
+        out = (yk * top_p[:, :, None].astype(yk.dtype)).sum(axis=1)
     out = out.reshape(b, s, d)
     if not return_stats:
         return out

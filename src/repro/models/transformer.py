@@ -56,9 +56,9 @@ def _init_attn(key, cfg: ModelConfig, g: int, cross: bool = False) -> dict:
         p["bq"] = jnp.zeros((g, h * hd), cfg.param_dtype)
         p["bk"] = jnp.zeros((g, hkv * hd), cfg.param_dtype)
         p["bv"] = jnp.zeros((g, hkv * hd), cfg.param_dtype)
-    if cfg.qk_norm:
-        p["q_norm"] = jnp.ones((g, hd), jnp.float32)
-        p["k_norm"] = jnp.ones((g, hd), jnp.float32)
+    if cfg.qk_norm:  # over the whole projection, as OLMoE normalises
+        p["q_norm"] = jnp.ones((g, h * hd), jnp.float32)
+        p["k_norm"] = jnp.ones((g, hkv * hd), jnp.float32)
     return p
 
 
@@ -187,7 +187,7 @@ def init_params(key, cfg: ModelConfig) -> dict:
 def _norm(x, p, cfg: ModelConfig):
     if cfg.norm == "ln":
         return L.layer_norm(x, p["w"], p["b"])
-    return L.rms_norm(x, p["w"], plus_one=(cfg.norm == "rms1p"))
+    return L.rms_norm(x, p["w"], cfg.norm_eps, plus_one=(cfg.norm == "rms1p"))
 
 
 def _apply_block(x, blk, kind, cfg, acfg, positions, cache, cache_pos, decode,

@@ -65,7 +65,7 @@ def init_params(key, cfg: ModelConfig) -> dict:
 def _norm(x, p, cfg):
     if cfg.norm == "ln":
         return L.layer_norm(x, p["w"], p["b"])
-    return L.rms_norm(x, p["w"])
+    return L.rms_norm(x, p["w"], cfg.norm_eps)
 
 
 def encode(params: dict, frames: Array, cfg: ModelConfig,
