@@ -242,10 +242,11 @@ class MatmulPlan:
 
 
 def _tile_log(acu: Acu):
-    """``(tiles, log)`` for a fused forward or backward plan: ``log(m, k,
-    n)``, called where a kernel is traced with an (m, k) x (k, n) GEMM (one
-    shard's under a partition), enters that shape's ``(bm, bk, bn)`` in
-    ``tiles`` as ``"MxKxN"``."""
+    """``(tiles, log)`` for a fused forward, backward or grouped plan:
+    ``log(m, k, n)``, called where a kernel is traced with an (m, k) x (k,
+    n) GEMM (one shard's under a partition; m is one group's capacity for
+    the grouped kernel), enters that shape's ``(bm, bk, bn)`` in ``tiles``
+    as ``"MxKxN"``."""
     from repro.kernels.fused_lut_dense.ops import tiles as pick
     from repro.kernels.lut_gather import lut_planes
     planes, tiles = lut_planes(acu.lut), {}
@@ -1101,11 +1102,13 @@ class GroupedPlan:
     ``route`` is one of
 
     * ``"fused_grouped"`` — ONE ``pallas_call`` for all E expert GEMMs
-      (``kernels/fused_lut_grouped``): the grid walks groups x row-blocks
-      and a per-group ``groupinfo = [row_base, row_count]`` operand skips
-      row-blocks past each group's live token count; in-kernel per-tensor
-      activation quantize, shifted-code LUT gathers, int32 accumulate with
-      integer-space K-pad correction, ONE combined-scale dequant.
+      (``kernels/fused_lut_grouped``): the grid walks groups x row strips
+      of the dense kernels' tile rule and a per-group ``groupinfo =
+      [row_base, row_count]`` operand skips strips, and the 128-row blocks
+      inside a strip, past each group's live token count; in-kernel
+      per-tensor activation quantize, shifted-code LUT gathers, int32
+      accumulate with integer-space K-pad correction, ONE combined-scale
+      dequant.
       ``fn(xe, wq, xs, xz, ws, counts) -> (G, cap, d_out) f32`` with ``xe``
       (G, cap, d_in) float dispatched activations, ``wq`` (E, d_in, d_out)
       shifted int weight codes, ``xs``/``xz`` per-tensor activation qparams
@@ -1123,6 +1126,10 @@ class GroupedPlan:
       ``approx_dense`` composition — which doubles as the bit-exactness
       oracle for the fused route when driven with the same pinned shared
       activation scale and live-row mask.
+
+    The fused route's ``tiles`` fill as its kernel is traced, keyed
+    ``"CxKxN"`` by one group's (one shard's) capacity and weight shape, as
+    :attr:`MatmulPlan.tiles`.
     """
 
     mode: AcuMode
@@ -1133,6 +1140,7 @@ class GroupedPlan:
     fn: Optional[Callable[..., Array]] = None
     partition: Optional[object] = None
     report: tuple[str, ...] = ()
+    tiles: Optional[dict] = dataclasses.field(default=None, compare=False)
 
     def __call__(self, *args) -> Array:
         assert self.fn is not None, f"route {self.route} has no direct kernel"
@@ -1140,7 +1148,7 @@ class GroupedPlan:
 
     def describe(self) -> dict:
         part = self.partition
-        return {
+        d = {
             "route": self.route,
             "mode": self.mode.value,
             "experts": self.spec.n_experts,
@@ -1155,6 +1163,9 @@ class GroupedPlan:
                 f"({part.n_rows}x{part.n_cols}x{part.n_k} way)",
             "report": list(self.report) + (list(part.report) if part else []),
         }
+        if self.tiles is not None:
+            d["tiles"] = dict(self.tiles)
+        return d
 
 
 def grouped_plan(acu: Acu, spec: GroupedSpec, *, a_bits: Optional[int] = None,
@@ -1190,9 +1201,11 @@ def grouped_plan(acu: Acu, spec: GroupedSpec, *, a_bits: Optional[int] = None,
                            report=tuple(report))
 
     from repro.kernels.fused_lut_grouped import ops as gops
+    tiles, log = _tile_log(acu)
 
     def grouped_call(xe, wq, xs, xz, ws, counts, *, emit_acc=False):
         # host table as is: see fused_call in matmul_plan
+        log(xe.shape[1], *wq.shape[1:])
         return gops.fused_lut_grouped(xe, wq, acu.lut, acu.offset, xs, xz,
                                       ws, counts,
                                       bits=a_bits, interpret=acu.interpret,
@@ -1212,7 +1225,7 @@ def grouped_plan(acu: Acu, spec: GroupedSpec, *, a_bits: Optional[int] = None,
 
     return GroupedPlan(mode=acu.mode, bits=acu.bits, use_pallas=True,
                        route="fused_grouped", spec=spec, fn=fn,
-                       partition=partition, report=tuple(report))
+                       partition=partition, report=tuple(report), tiles=tiles)
 
 
 def make_acu(name: str, mode: AcuMode | str = AcuMode.LUT, rank: int = 8,

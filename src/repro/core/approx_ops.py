@@ -391,6 +391,22 @@ def approx_grouped_dense(xe: Array, w: Array, cfg: ApproxConfig,
     return y.astype(xe.dtype)
 
 
+def grouped_tile(cfg: ApproxConfig, cap: int, d_in: int,
+                 d_out: int) -> Optional[tuple[int, int, int]]:
+    """The (bm, bk, bn) tile the grouped kernel of
+    :func:`approx_grouped_dense` runs on one device for capacity buffers
+    of ``cap`` rows and (d_in, d_out) expert weights, or None where that
+    GEMM takes the per-expert route."""
+    if cfg.fake_quant_only:
+        return None
+    spec = GroupedSpec(n_experts=1, cap=cap, d_in=d_in, d_out=d_out)
+    if grouped_plan(cfg.acu, spec, mesh=False).route != "fused_grouped":
+        return None
+    from repro.kernels.fused_lut_dense.ops import tiles
+    from repro.kernels.lut_gather import lut_planes
+    return tiles(cap, d_in, d_out, lut_planes(cfg.acu.lut))
+
+
 # ---------------------------------------------------------------------------
 # Approximate attention: quantize -> LUT-gather QK^T / PV inside the
 # streaming-softmax kernel (kernels/flash_attention/approx.py), routed by

@@ -3,17 +3,20 @@
 ONE ``pallas_call`` runs all E expert GEMMs of an MoE layer. The input is the
 dispatched capacity buffer flattened to ``(G * Cp, K)`` rows, where each of
 the ``G`` groups (``G = nb * E`` dispatch blocks x experts) owns a contiguous
-strip of ``Cp`` padded capacity rows and multiplies against the weights of
-expert ``g % E``. The grid walks ``(group, row-block, n-block, k-block)`` and
-a per-group ``groupinfo = [row_base, row_count]`` operand — the same pattern
+run of ``Cp`` padded capacity rows and multiplies against the weights of
+expert ``g % E``. The grid walks ``(group, strip, n-block, k-block)`` over
+``bm``-row strips (up to 640 rows, the dense kernels' tile rule) and a
+per-group ``groupinfo = [row_base, row_count]`` operand — the same pattern
 as flash-attention's per-row ``rowinfo`` extents — tells the kernel how many
-of each group's capacity rows actually hold routed tokens, so row-blocks past
-the live count skip the quantize + LUT-gather work entirely instead of
-grinding through dead padded slots. That skip is the whole point: a capacity
-buffer at ``moe_capacity`` 1.25+ with realistic (skewed) routing is mostly
-dead rows.
+of each group's capacity rows actually hold routed tokens. A strip past the
+live count skips the quantize + LUT-gather work entirely; inside a live
+strip the core builds its gathered table planes once for the whole strip
+and runs the one-hots and products only for the 128-row blocks that hold a
+live row (``lut_gemm_streamed(live_rows=...)``). That skip is the whole
+point: a capacity buffer at ``moe_capacity`` 1.25+, or a dropless one, is
+mostly dead rows.
 
-Inside a live block the body is the established fused recipe, verbatim from
+Inside a live strip the body is the established fused recipe, verbatim from
 ``fused_lut_dense``: per-tensor in-kernel activation quantization of each
 128-wide contraction piece, the shared LUT-GEMM core
 (:mod:`repro.kernels.lut_gather`), int32 accumulate into a persistent VMEM
@@ -60,13 +63,13 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, m00_ref, ws_ref, info_ref,
 
     bm, bn = acc_ref.shape
     count = info_ref[g, 1]                 # live rows in this group
-    live = count - m_step * bm             # live rows at/after this row-block
+    live = count - m_step * bm             # live rows at/after this strip
 
     @pl.when(live > 0)
     def _accumulate():
-        # fused_lut_dense recipe verbatim — only executed for row-blocks that
-        # intersect the group's live rows; dead blocks skip straight past the
-        # quantize + gather work (the ragged-dispatch win)
+        # fused_lut_dense recipe verbatim, run only for strips that hold a
+        # live row; inside one, the core builds the gathered planes once for
+        # the strip and skips the 128-row blocks past the live rows
         xs = xs_ref[0]                             # per-tensor activation scale
         xz = xz_ref[0]                             # activation zero-point (code)
 
@@ -80,15 +83,16 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, m00_ref, ws_ref, info_ref,
 
         acc_ref[...] += lut_gemm_streamed(load_a, load_b,
                                           x_ref.shape[1] // LANES, lut_ref,
-                                          m=bm, n=bn, n_planes=n_planes)
+                                          m=bm, n=bn, n_planes=n_planes,
+                                          live_rows=live)
 
     @pl.when(k_step == pl.num_programs(3) - 1)
     def _dequant():
         acc = acc_ref[...]
         if k_pad:  # padded k entries each contributed LUT[off, off] = M[0, 0]
-            # applied unconditionally: dead row-blocks never accumulated, so
-            # their value here is garbage either way — the row mask below is
-            # what guarantees they emit exactly zero
+            # applied unconditionally: dead rows never accumulated, so their
+            # value here is garbage either way — the row mask below is what
+            # guarantees they emit exactly zero
             acc = acc - k_pad * m00_ref[0]
         row = m_step * bm + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
         if emit_acc:
@@ -106,26 +110,27 @@ def _kernel(x_ref, w_ref, lut_ref, xs_ref, xz_ref, m00_ref, ws_ref, info_ref,
 
 @functools.partial(jax.jit, static_argnames=("offset", "lo", "hi", "k_pad",
                                              "n_planes", "cp", "bm", "bk",
-                                             "interpret", "emit_acc"))
+                                             "bn", "interpret", "emit_acc"))
 def fused_lut_grouped_kernel(x: jnp.ndarray, wq: jnp.ndarray, lut: jnp.ndarray,
                              x_scale: jnp.ndarray, x_zp: jnp.ndarray,
                              m00: jnp.ndarray, w_scale: jnp.ndarray,
                              info: jnp.ndarray, *, offset: int, lo: int,
-                             hi: int, cp: int, k_pad: int = 0,
-                             n_planes: int = 4, bm: int = 128, bk: int = 128,
+                             hi: int, cp: int, bm: int, bk: int, bn: int,
+                             k_pad: int = 0, n_planes: int = 4,
                              interpret: bool | None = None,
                              emit_acc: bool = False) -> jnp.ndarray:
     """x: (G * cp, K) float rows, group g owning rows [g*cp, (g+1)*cp);
     wq: (E, K, N) shifted int weight codes (group g uses expert g % E);
     lut: the (R, L) padded table; x_scale/x_zp: shape-(1,) f32; m00:
     shape-(1,) int32 ``LUT[off, off]``; w_scale: (E, 1, N) f32; info: (G, 2)
-    int32 ``[row_base, row_count]``. ``K`` and ``N`` are multiples of 128.
-    Returns (G * cp, N) float32 with rows >= row_count exactly 0.0 — or the
-    raw int32 accumulator (dead rows zeroed) with ``emit_acc=True``."""
+    int32 ``[row_base, row_count]``. ``K`` and ``N`` are multiples of 128;
+    the tile is ``bm``-row strips by ``bn`` columns, ``bk`` of the
+    contraction a grid step. Returns (G * cp, N) float32 with rows >=
+    row_count exactly 0.0 — or the raw int32 accumulator (dead rows zeroed)
+    with ``emit_acc=True``."""
     Gm, K = x.shape
     E, _, N = wq.shape
     G = Gm // cp
-    bn = LANES
     assert Gm == G * cp and G % E == 0, (Gm, cp, E)
     assert cp % bm == 0 and K % bk == 0 and N % bn == 0 and bk % LANES == 0, (
         f"shape {(cp, K, N)} not divisible by tile {(bm, bk, bn)}")

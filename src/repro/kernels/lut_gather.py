@@ -32,7 +32,10 @@ whole (M, N) output block: the gathered planes once per 128-lane column
 chunk, shared by every row, and the one-hot once per ``ROW_BLOCK`` rows,
 shared by every column chunk. A larger output block therefore spreads the
 expansion over more MXU work (the dense kernels' tile choice,
-``fused_lut_dense/ops.tiles``).
+``fused_lut_dense/ops.tiles``). A ragged caller (the grouped expert kernel)
+passes the block's live row count: the gathered planes are still built
+once for the block, and the one-hots and products run only for the row
+blocks that hold a live row.
 
 Tables arrive padded by :func:`pad_lut` to ``(R, L)`` with ``R`` and ``L``
 multiples of 128; padded entries are never indexed.
@@ -160,17 +163,19 @@ def _onehot(col, r: int):
                             for c in range(0, r, LANES)], axis=1)
 
 
-def _accumulate(acc, a_col, b_rows, packed, r: int):
+def _accumulate(acc, a_col, b_rows, packed, r: int, live_rows=None):
     """Add one chunk of ``w`` contraction indices to the block accumulators
     ``acc[i][c]`` (row block ``i``, 128-lane column chunk ``c``).
     ``a_col(i, j)`` gives row block ``i`` of the chunk's ``j``-th code
     column broadcast across 128 lanes; ``b_rows`` is the chunk's (w, N)
     codes. The gathered planes serve every row block, each one-hot every
-    column chunk."""
+    column chunk. With a traced ``live_rows`` (at least 1), row block ``i``
+    above the first runs only where ``i * ROW_BLOCK < live_rows``; the
+    others keep their accumulators."""
     planes = [_gather_planes(packed, b_rows[:, n0:n0 + LANES])
               for n0 in range(0, b_rows.shape[1], LANES)]
-    out = []
-    for i, row in enumerate(acc):
+
+    def row_block(i, row):
         onehot = jnp.concatenate([_onehot(a_col(i, j), r)
                                   for j in range(b_rows.shape[0])], axis=1)
         new = []
@@ -179,17 +184,27 @@ def _accumulate(acc, a_col, b_rows, packed, r: int):
                 part = jnp.dot(onehot, d, preferred_element_type=jnp.int32)
                 blk = blk + (part << (8 * p) if p else part)
             new.append(blk)
-        out.append(new)
+        return new
+
+    out = []
+    for i, row in enumerate(acc):
+        if live_rows is None or i == 0:
+            out.append(row_block(i, row))
+        else:
+            out.append(jax.lax.cond(i * ROW_BLOCK < live_rows,
+                                    functools.partial(row_block, i),
+                                    lambda row: row, row))
     return out
 
 
-def _piece(acc, a, b_rows, packed, r: int, k: int):
+def _piece(acc, a, b_rows, packed, r: int, k: int, live_rows=None):
     """Add the first ``k`` contraction indices of a 128-wide piece: ``a``
     (M, 128) codes and ``b_rows(kt, w)``, the piece's (w, N) code rows from
     a traced ``kt``. A ``fori_loop`` walks the chunks of ``ONEHOT_WIDTH //
     R`` indices (a shorter tail chunk runs after it), so the program holds
     one chunk whatever the contraction length; a chunk takes its code
-    columns of ``a`` by lane gathers at the traced index."""
+    columns of ``a`` by lane gathers at the traced index. ``live_rows`` as
+    in :func:`_accumulate`."""
     kc = ONEHOT_WIDTH // r
     rows = _row_blocks(a.shape[0])
 
@@ -201,7 +216,7 @@ def _piece(acc, a, b_rows, packed, r: int, k: int):
             at = jnp.full((size, LANES), kt + j, jnp.int32)
             return jnp.take_along_axis(a[r0:r0 + size], at, axis=1)
 
-        return _accumulate(acc, a_col, b_rows(kt, w), packed, r)
+        return _accumulate(acc, a_col, b_rows(kt, w), packed, r, live_rows)
 
     acc = jax.lax.fori_loop(0, k // kc, chunk, acc)
     return chunk(k // kc, acc, k % kc) if k % kc else acc
@@ -269,18 +284,24 @@ def lut_gemm(a, b, lut, *, n_planes: int):
 
 
 def lut_gemm_streamed(load_a, load_b, n_pieces: int, lut, *, m: int, n: int,
-                      n_planes: int):
+                      n_planes: int, live_rows=None):
     """:func:`lut_gemm` over a contraction streamed from refs in 128-wide
     aligned pieces: ``load_a(k0)`` -> (m, 128) codes, ``load_b(k0)`` ->
     (128, n) codes, ``k0`` a multiple of 128; ``n`` a multiple of 128. A
     ``fori_loop`` walks the pieces, so the kernel body does not grow with
-    the contraction length."""
+    the contraction length.
+
+    ``live_rows``, a traced int32 of at least 1, bounds the rows that
+    matter: the one-hots and products of each ``ROW_BLOCK``-row block
+    past it are skipped, and those rows come back 0, while the gathered
+    planes are still built once for all of the ``m`` rows. ``None`` runs
+    every row block."""
     r = lut.shape[0]
     packed = _packed_planes(lut, n_planes)
 
     def piece(i, acc):
         k0 = pl.multiple_of(i * LANES, LANES)
         return _piece(acc, load_a(k0), _pick_rows(load_b(k0), r), packed, r,
-                      LANES)
+                      LANES, live_rows)
 
     return _assemble(jax.lax.fori_loop(0, n_pieces, piece, _zeros(m, n)))

@@ -27,7 +27,8 @@ Two dispatch layouts (cfg.moe_shard_dispatch — §Perf hillclimb #1):
 Expert GEMMs under an ``ApproxConfig`` run as ONE grouped ragged fused
 LUT-GEMM per projection (``approx_grouped_dense`` — docs/moe.md): all
 ``nb * E`` capacity buffers walk a single ``pallas_call`` whose groupinfo
-lets it skip row-blocks past each group's live token count, instead of
+lets it skip the row strips, and the 128-row blocks inside a strip, past
+each group's live token count, instead of
 launching E (or nb*E) separate kernels that all run ``cap`` rows. QAT
 (``fake_quant_only``) keeps the per-expert vmapped path — fake-quant has no
 LUT kernel to fuse.
@@ -39,7 +40,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.approx_ops import ApproxConfig, approx_dense, approx_grouped_dense
+from repro.core.approx_ops import (ApproxConfig, approx_dense,
+                                   approx_grouped_dense, grouped_tile)
+from repro.kernels.lut_gather import ROW_BLOCK
 from repro.parallel.sharding import current_mesh_context, shard
 
 Array = jnp.ndarray
@@ -74,7 +77,7 @@ def _expert_ffn(xe: Array, p: dict, cfg, acfg, block_axes,
 
     ``counts`` (matching xe's leading block/expert dims) gives the live row
     count of each capacity buffer; with an approx config the three
-    projections run as grouped ragged fused LUT-GEMMs that skip row-blocks
+    projections run as grouped ragged fused LUT-GEMMs that skip the rows
     past the counts. Rows at or beyond a buffer's count come back exactly
     0.0 from the grouped path (dead-row contract, see docs/moe.md).
     """
@@ -167,6 +170,14 @@ def moe_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig],
                         softmax — bitwise-identical to ``router_aux_loss``).
       ``dropped_frac``  fraction of the T*k routed assignments dropped by
                         the capacity limit (f32 scalar).
+
+    and, where the expert GEMMs run the grouped kernel, its row work in
+    each projection (int32 scalars, from the live counts and the kernel's
+    tile for the gate and up projections' shape):
+      ``live_blocks``   the 128-row blocks that hold a live row, summed over
+                        the capacity buffers: the one-hot and product work.
+      ``live_strips``   the row strips the kernel runs a body for: each
+                        builds the gathered table planes once.
     """
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.moe_top_k
@@ -228,6 +239,11 @@ def moe_block(x: Array, p: dict, cfg, acfg: Optional[ApproxConfig],
         "aux_loss": _aux_loss(probs, top_e, e),
         "dropped_frac": 1.0 - keep.mean(dtype=jnp.float32),
     }
+    tile = None if acfg is None else grouped_tile(acfg, cap, d,
+                                                  p["w_gate"].shape[-1])
+    if tile is not None:
+        stats["live_blocks"] = jnp.sum(-(-counts // ROW_BLOCK))
+        stats["live_strips"] = jnp.sum(-(-counts // tile[0]))
     return out, stats
 
 

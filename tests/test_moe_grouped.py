@@ -89,8 +89,13 @@ def _kernel_oracle(x, wq, lut, xqp, ws, mask):
     (4, 4, 24, 600, 14, False, None),           # K > 512 -> k-tiled grid
     (6, 3, 16, 40, 9, True, [0, 16, 3, 0, 16, 5]),  # empty experts
     (4, 4, 32, 40, 9, False, [32, 0, 0, 0]),    # all tokens to one expert
+    # 512-row strips of four 128-row blocks: a dead strip, one live row,
+    # block edges, a strip one row short of full, a full strip
+    (6, 6, 512, 40, 9, False, [0, 1, 128, 129, 511, 512]),
+    (4, 4, 256, 600, 9, False, None),           # 256-row strips, k-tiled
+    (4, 2, 512, 40, 9, True, [129, 0, 512, 300]),   # strips, biased M00
 ], ids=["ragged", "blocks", "biased_m00", "ktile", "empty_experts",
-        "all_to_one"])
+        "all_to_one", "strip_edges", "strip_ktile", "strip_biased_m00"])
 def test_grouped_kernel_bitwise_vs_per_expert(case):
     G, E, C, K, N, biased, counts = case
     lut = BLUT if biased else LUT
@@ -122,6 +127,50 @@ def test_grouped_kernel_biased_dead_rows_exact_zero():
     dq = acc.astype(jnp.float32) * (xqp.scale * ws[:, None, :])[
         jnp.arange(4) % 2]
     assert jnp.array_equal(out, jnp.where(mask[..., None], dq, 0.0))
+
+
+def test_grouped_kernel_emit_acc_strips():
+    """emit_acc over 512-row strips under the biased multiplier: each live
+    row's int32 accumulator equals the per-expert dense kernel's, and every
+    dead row, in a live strip or a dead one, is 0 in integer space."""
+    x, w, counts, mask = _grouped_operands(4, 2, 512, 40, 9, seed=23,
+                                           counts=[200, 0, 512, 1])
+    xqp, wq, ws = _quantized(x, w)
+    acc = fused_lut_grouped(x, wq, BLUT, 128, xqp.scale, xqp.zero_point, ws,
+                            counts, bits=8, interpret=True, emit_acc=True)
+    ref = jnp.stack([
+        jnp.where(mask[g][:, None], fused_lut_dense(
+            x[g], wq[g % 2], BLUT, 128, xqp.scale, xqp.zero_point,
+            ws[g % 2], bits=8, interpret=True, emit_acc=True), 0)
+        for g in range(4)])
+    assert acc.dtype == jnp.int32
+    assert jnp.array_equal(acc, ref)
+
+
+def test_grouped_tile_rule():
+    """The grouped kernel takes the dense kernels' tile for one group's
+    capacity: 512 x 512 strips at OLMoE's expert shapes, one strip of
+    round_up(C, 8) rows for C <= 128, every pick inside the default scoped
+    VMEM; the plan lists each traced tile."""
+    from repro.kernels.fused_lut_dense.kernel import (DEFAULT_VMEM,
+                                                      gemm_vmem_bytes)
+    from repro.kernels.lut_gather import lut_planes
+    planes = lut_planes(ACU.lut)
+    cases = {(4096, 2048, 1024): (512, 256, 512),
+             (4096, 1024, 2048): (512, 256, 512),
+             (24, 33, 14): (24, 128, 128),
+             (100, 600, 256): (104, 128, 256),
+             (128, 576, 1536): (128, 128, 512)}
+    for (C, K, N), want in cases.items():
+        plan = grouped_plan(ACU, GroupedSpec(n_experts=2, cap=C, d_in=K,
+                                             d_out=N))
+        jax.eval_shape(plan, jax.ShapeDtypeStruct((2, C, K), jnp.float32),
+                       jax.ShapeDtypeStruct((2, K, N), jnp.int32),
+                       jnp.float32(0.02), jnp.float32(0.0),
+                       jax.ShapeDtypeStruct((2, N), jnp.float32),
+                       jax.ShapeDtypeStruct((2,), jnp.int32))
+        assert plan.describe()["tiles"] == {f"{C}x{K}x{N}": want}
+        assert gemm_vmem_bytes(*want, planes) <= DEFAULT_VMEM
 
 
 def test_grouped_kernel_jit_parity():
@@ -330,6 +379,31 @@ def test_dropped_frac_pinned_at_low_capacity():
     expect = dropped / (t * cfg.moe_top_k)
     assert dropped > 0                      # the capacity really binds
     assert float(stats["dropped_frac"]) == pytest.approx(expect, abs=1e-7)
+
+
+def test_live_blocks_and_strips_hand_count():
+    """moe_block's live_blocks / live_strips against a hand count of the
+    routing: dropless, one dispatch block, so each expert's buffer holds
+    1024 rows and the grouped kernel runs 512-row strips."""
+    cfg = dataclasses.replace(CFG_MOE, moe_capacity=None,
+                              moe_shard_dispatch=False)
+    p = _moe_params(cfg)
+    x = jax.random.normal(KEY, (2, 512, 32))
+    _, stats = moe_block(x, p, cfg, CFG_A, return_stats=True)
+    logits = np.asarray(x.reshape(-1, 32), np.float32) @ \
+        np.asarray(p["router"], np.float32)
+    top_e = np.asarray(jax.lax.top_k(jnp.asarray(logits), cfg.moe_top_k)[1])
+    blocks = strips = 0
+    for e in range(cfg.n_experts):
+        rows = int((top_e == e).sum())
+        blocks += -(-rows // 128)
+        strips += -(-rows // 512)
+    assert strips >= cfg.n_experts                  # every expert is live
+    assert int(stats["live_blocks"]) == blocks
+    assert int(stats["live_strips"]) == strips
+    # no grouped kernel, no row-work stats
+    _, exact = moe_block(x, p, cfg, None, return_stats=True)
+    assert "live_blocks" not in exact and "live_strips" not in exact
 
 
 def test_dropped_frac_zero_with_ample_capacity():

@@ -108,6 +108,11 @@ CASES = {
                                                      ((2048, F), f32)]),
     "fused_lut_grouped": (_grouped, [((8, 32, D), f32), ((4, D, F), i32),
                                      ((4, F), f32), ((8,), i32)]),
+    # OLMoE's gate/up expert GEMM over dropless 4096-row capacity buffers:
+    # 512 x 512 strips that skip their dead 128-row blocks
+    "fused_lut_grouped_strips": (_grouped, [((8, 4096, 2048), jnp.bfloat16),
+                                            ((8, 2048, 1024), i32),
+                                            ((8, 1024), f32), ((8,), i32)]),
     "approx_flash_attention": (_attn, [((HQ, 128, HD), f32),
                                        ((HKV, 128, HD), f32),
                                        ((HKV, 128, HD), f32)]),
